@@ -34,6 +34,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 import bench_util  # noqa: E402
 
 from repro.core import costmodel as cm, engine, harness, programs  # noqa: E402
+from repro.launch.cache import enable_compile_cache  # noqa: E402
 
 BENCH_JSON = "BENCH_engine.json"
 
@@ -359,6 +360,7 @@ def main(argv=None) -> int:
                     help="fail (exit 1) if blocks64/blocks1 packed-"
                     "resident throughput (sim_mops_compiled) is below X")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     # gates run BEFORE the artifact exists: a failing gate exits 1 with
     # one line and writes nothing for CI to "validate"
     payload = run(json_path=None, quick=args.quick)

@@ -48,6 +48,7 @@ import bench_util  # noqa: E402
 
 from repro.pim import fabric  # noqa: E402
 from repro.pim.fabric import FabricConfig  # noqa: E402
+from repro.launch.cache import enable_compile_cache  # noqa: E402
 
 BENCH_JSON = "BENCH_fabric.json"
 
@@ -314,6 +315,7 @@ def main(argv=None) -> int:
                     help="fail (exit 1) if the autotuner's gain over "
                     "the default geometry drops below X")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     # gates run BEFORE the artifact exists (see bench_util)
     payload = run(json_path=None, quick=args.quick)
     bad = []

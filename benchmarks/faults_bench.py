@@ -45,6 +45,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
 from repro.core.faults import FaultModel  # noqa: E402
 from repro.pim import fabric  # noqa: E402
+from repro.launch.cache import enable_compile_cache  # noqa: E402
 
 BENCH_JSON = "BENCH_faults.json"
 REPRO_JSON = "BENCH_faults_repro.json"
@@ -226,6 +227,7 @@ def main(argv=None) -> int:
     ap.add_argument("--gate", action="store_true",
                     help="enforce the fault gates (exit 1 on failure)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     # gates run BEFORE the artifact exists (see bench_util)
     payload = run(json_path=None, quick=args.quick)
     bad = check_gates(payload) if args.gate else []

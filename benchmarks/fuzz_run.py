@@ -38,6 +38,7 @@ import time
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
 from repro.core import fuzz  # noqa: E402
+from repro.launch.cache import enable_compile_cache  # noqa: E402
 
 DEFAULT_CORPUS = pathlib.Path(__file__).resolve().parents[1] / "tests" / "corpus"
 BENCH_JSON = "BENCH_fuzz.json"
@@ -99,6 +100,7 @@ def main(argv=None) -> int:
                     help=f"also write campaign stats JSON (e.g. "
                     f"{BENCH_JSON})")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = fuzz.FuzzConfig(rows=args.rows, cols=args.cols,
                           max_ops=args.max_ops,

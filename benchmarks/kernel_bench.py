@@ -29,6 +29,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 import bench_util  # noqa: E402
 
 from repro.kernels import ops, ref  # noqa: E402
+from repro.launch.cache import enable_compile_cache  # noqa: E402
 
 BENCH_JSON = "BENCH_kernels.json"
 
@@ -143,6 +144,7 @@ def main(argv=None) -> int:
                     help="fail (exit 1) if the packed-weight HBM traffic "
                     "reduction (vs bf16) drops below X for any precision")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     # gates run BEFORE the artifact exists (see bench_util)
     payload = run(json_path=None, quick=args.quick)
     bad = []

@@ -15,8 +15,11 @@ import pathlib
 
 
 def main() -> None:
+    from repro.launch.cache import enable_compile_cache
+
     from . import (app_projection, engine_bench, figures, kernel_bench,
                    serve_bench, table2_blocks)
+    enable_compile_cache()
     print("name,value,derived")
     table2_blocks.run()
     figures.run()

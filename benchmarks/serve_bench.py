@@ -65,6 +65,7 @@ import bench_util  # noqa: E402
 from repro import configs  # noqa: E402
 from repro.models.model import LM  # noqa: E402
 from repro.serve.engine import Request, ServeEngine  # noqa: E402
+from repro.launch.cache import enable_compile_cache  # noqa: E402
 
 BENCH_JSON = "BENCH_serve.json"
 
@@ -368,6 +369,7 @@ def main(argv=None) -> int:
                     metavar="TPS", help="fail if sweep throughput drops "
                     "below TPS generated tokens/sec")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     # gates run BEFORE the artifact exists (see bench_util)
     payload = run(json_path=None, quick=args.quick,
                   n_requests=args.requests, seed=args.seed)

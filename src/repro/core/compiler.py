@@ -39,6 +39,7 @@ import dataclasses
 from typing import Dict, List, Optional, Sequence
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 
@@ -1513,13 +1514,11 @@ def cse_jaxpr(closed):
 
     Returns ``(new_closed_jaxpr, n_removed)``.
     """
-    import jax.core as jcore
-
     jaxpr = closed.jaxpr
     subst: Dict = {}
 
     def canon(v):
-        if isinstance(v, jcore.Literal):
+        if isinstance(v, jex_core.Literal):
             return v
         return subst.get(v, v)
 
@@ -1532,8 +1531,8 @@ def cse_jaxpr(closed):
         if not eqn.effects:
             parts = [_freeze(dict(eqn.params))]
             for v in invars:
-                parts.append(_literal_key(v) if isinstance(v, jcore.Literal)
-                             else v)
+                parts.append(_literal_key(v)
+                             if isinstance(v, jex_core.Literal) else v)
             if all(p is not None for p in parts):
                 key = (eqn.primitive, tuple(parts))
         if key is not None:
@@ -1541,11 +1540,11 @@ def cse_jaxpr(closed):
             # every output the duplicate defines must exist on the kept
             # eqn (a DropVar there has no value to forward)
             if hit is not None and all(
-                    isinstance(old, jcore.DropVar)
-                    or not isinstance(new, jcore.DropVar)
+                    isinstance(old, jax.core.DropVar)
+                    or not isinstance(new, jax.core.DropVar)
                     for old, new in zip(eqn.outvars, hit)):
                 for old, new in zip(eqn.outvars, hit):
-                    if not isinstance(old, jcore.DropVar):
+                    if not isinstance(old, jax.core.DropVar):
                         subst[old] = new
                 removed += 1
                 continue
@@ -1555,36 +1554,27 @@ def cse_jaxpr(closed):
             table[key] = eqn.outvars
     new_jaxpr = jaxpr.replace(
         eqns=new_eqns, outvars=[canon(v) for v in jaxpr.outvars])
-    return jcore.ClosedJaxpr(new_jaxpr, closed.consts), removed
+    return jex_core.ClosedJaxpr(new_jaxpr, closed.consts), removed
 
 
 def apply_cse(fn, *example_args):
     """Wrap ``fn`` so it evaluates through a CSE'd jaxpr (un-jitted).
 
     ``example_args`` are pytrees of arrays or ``jax.ShapeDtypeStruct``
-    giving the call signature to trace.  On ANY failure the original
-    ``fn`` is returned untouched -- the pass is an optimization, never a
-    correctness dependency.  The returned callable carries a
+    giving the call signature to trace.  The returned callable carries a
     ``_cse_stats`` dict (eqn counts) for benchmarks.
     """
-    import jax.core as jcore
+    closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*example_args)
+    n_before = len(closed.jaxpr.eqns)
+    new_closed, removed = cse_jaxpr(closed)
+    out_tree = jax.tree_util.tree_structure(out_shape)
 
-    try:
-        closed, out_shape = jax.make_jaxpr(
-            fn, return_shape=True)(*example_args)
-        n_before = len(closed.jaxpr.eqns)
-        new_closed, removed = cse_jaxpr(closed)
-        out_tree = jax.tree_util.tree_structure(out_shape)
+    def cse_fn(*args):
+        flat = jax.tree_util.tree_leaves(args)
+        outs = jax.core.eval_jaxpr(new_closed.jaxpr, new_closed.consts, *flat)
+        return jax.tree_util.tree_unflatten(out_tree, outs)
 
-        def cse_fn(*args):
-            flat = jax.tree_util.tree_leaves(args)
-            outs = jcore.eval_jaxpr(new_closed.jaxpr, new_closed.consts,
-                                    *flat)
-            return jax.tree_util.tree_unflatten(out_tree, outs)
-
-        cse_fn._cse_stats = {"eqns_before": n_before,
-                             "eqns_after": n_before - removed,
-                             "removed": removed}
-        return cse_fn
-    except Exception:                                   # pragma: no cover
-        return fn
+    cse_fn._cse_stats = {"eqns_before": n_before,
+                         "eqns_after": n_before - removed,
+                         "removed": removed}
+    return cse_fn

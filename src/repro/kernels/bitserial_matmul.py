@@ -34,23 +34,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import ref
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x;
-# support both so the kernels import on whichever the image bakes in.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+def _tile(dim: int, target: int, align: int) -> int:
+    """Block size along one array dimension for Mosaic.
 
-
-def _pick_block(dim: int, target: int, mult: int) -> int:
-    """Largest divisor of ``dim`` that is <= target and a multiple of
-    ``mult`` (so odd model dims like 896 or 4864 still tile cleanly)."""
-    best = None
-    for d in range(min(target, dim), 0, -1):
-        if dim % d == 0 and d % mult == 0:
-            best = d
-            break
-    if best is None:
-        raise ValueError(f"no block for dim={dim} target={target} mult={mult}")
-    return best
+    The largest divisor of ``dim`` that is <= ``target`` and a multiple
+    of ``align`` (the TPU tiling of that dimension); the full ``dim``
+    when there is none, since a block spanning the whole dimension is
+    always legal.  Odd model widths (896, 4864) thus still tile."""
+    for d in range(min(target, dim) // align * align, 0, -align):
+        if dim % d == 0:
+            return d
+    return dim
 
 
 # ---------------------------------------------------------------------------
@@ -62,7 +56,7 @@ def _unpack_matmul_kernel(a_ref, w_ref, s_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...].astype(jnp.int8)                       # (bm, bk)
+    a = a_ref[...]                                        # (bm, bk) int8
     shifts = jnp.arange(32, dtype=jnp.uint32)
     coefs = ref.plane_coefs(bits, signed=True)
 
@@ -73,8 +67,10 @@ def _unpack_matmul_kernel(a_ref, w_ref, s_ref, o_ref, acc_ref, *,
         bitv = (wp[:, None, :] >> shifts[None, :, None]) & jnp.uint32(1)
         w = w + coefs[b] * bitv.reshape(block_k, bn).astype(jnp.int32)
 
+    # int8 x int8 on the MXU: a two's-complement weight of <= 8 bits
+    # fits int8 exactly
     acc_ref[...] += jax.lax.dot_general(
-        a.astype(jnp.int32), w, (((1,), (0,)), ((), ())),
+        a, w.astype(jnp.int8), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
@@ -92,15 +88,17 @@ def quant_matmul(a, w_packed, scale_w, *, bits: int,
     """C = (A @ unpack(W_packed)) * scale_w.
 
     a: (M, K) int8;  w_packed: (bits, K//32, N) uint32;  scale_w: (N,) f32.
-    M/N/K must divide by the block shapes (callers pad; model dims are
-    MXU-aligned anyway).
+    The block sizes are targets (see :func:`_tile`): int8 rows tile by
+    32, lanes by 128, and ``block_k`` by 256 so that the packed-word
+    block (``block_k // 32``) is a whole number of 8-row tiles.
     """
     m, k = a.shape
     n = w_packed.shape[-1]
     assert w_packed.shape == (bits, k // 32, n), w_packed.shape
-    block_m = _pick_block(m, block_m, 1)
-    block_n = _pick_block(n, block_n, 1)
-    block_k = _pick_block(k, block_k, 32)
+    assert bits <= 8, bits
+    block_m = _tile(m, block_m, 32)
+    block_n = _tile(n, block_n, 128)
+    block_k = _tile(k, block_k, 256)
 
     grid = (m // block_m, n // block_n, k // block_k)
     return pl.pallas_call(
@@ -115,7 +113,7 @@ def quant_matmul(a, w_packed, scale_w, *, bits: int,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, w_packed, scale_w.reshape(1, n).astype(jnp.float32))
@@ -146,37 +144,41 @@ def _popcount_kernel(ap_ref, wp_ref, o_ref, acc_ref, *, ca, cw):
                                              "block_m", "block_n", "block_k",
                                              "interpret"))
 def popcount_matmul(a_packed, w_packed, *, a_signed: bool = True,
-                    w_signed: bool = True, block_m: int = 32,
-                    block_n: int = 128, block_k: int = 256,
+                    w_signed: bool = True, block_m: int = 8,
+                    block_n: int = 128, block_k: int = 4096,
                     interpret: bool = False):
     """(M, N) int32 = bit-serial matmul of packed planes (exact).
 
     a_packed: (Ba, M, K//32) uint32;  w_packed: (Bw, K//32, N) uint32.
+    The packed-word block (``block_k // 32``) is the lane dimension of
+    the activation block, so it tiles by 128 words or spans all of K.
+    ``block_m`` stays at 8 rows: the kernel's (bm, words, bn) AND
+    intermediates must fit the 16 MiB of scoped VMEM on a v5e at
+    K = 4864, which 32 rows overflow.
     """
     ba, m, kw = a_packed.shape
     bw, kw2, n = w_packed.shape
     assert kw == kw2, (kw, kw2)
-    k = kw * 32
-    block_m = _pick_block(m, block_m, 1)
-    block_n = _pick_block(n, block_n, 1)
-    block_k = _pick_block(k, block_k, 32)
+    block_m = _tile(m, block_m, 8)
+    block_n = _tile(n, block_n, 128)
+    block_kw = _tile(kw, block_k // 32, 128)
 
     ca = ref.plane_coefs(ba, a_signed)
     cw = ref.plane_coefs(bw, w_signed)
-    grid = (m // block_m, n // block_n, k // block_k)
+    grid = (m // block_m, n // block_n, kw // block_kw)
     return pl.pallas_call(
         functools.partial(_popcount_kernel, ca=ca, cw=cw),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((ba, block_m, block_k // 32),
+            pl.BlockSpec((ba, block_m, block_kw),
                          lambda i, j, t: (0, i, t)),
-            pl.BlockSpec((bw, block_k // 32, block_n),
+            pl.BlockSpec((bw, block_kw, block_n),
                          lambda i, j, t: (0, t, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a_packed, w_packed)

@@ -1,7 +1,9 @@
-"""Public jit'd API over the Pallas kernels (with CPU interpret fallback).
+"""Public jit'd API over the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the whole framework runs (and
-is tested) on CPU; on TPU the kernels compile to Mosaic.
+``interpret`` defaults to True on the CPU backend, so the whole framework
+runs (and is tested) there; on every other backend the kernels compile
+to Mosaic, and a backend Mosaic cannot target raises instead of quietly
+interpreting.
 """
 
 from __future__ import annotations
@@ -20,7 +22,7 @@ plane_coefs = kref.plane_coefs
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def quant_matmul(a, w_packed, scale_w, *, bits: int, interpret=None, **kw):

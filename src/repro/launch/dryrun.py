@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware: sharding
@@ -16,6 +13,7 @@ Usage:
 
 import argparse
 import json
+import os
 import pathlib
 import time
 import traceback
@@ -77,7 +75,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool = False,
     kind = shp.SHAPES[shape_name]["kind"]
     t0 = time.time()
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if wq_bits:
             from repro.models.qweight import quantize_tree
             params_avals = jax.eval_shape(
@@ -184,7 +182,15 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool = False,
 ALL_CELLS = [(a, s) for a in configs.list_archs() for s in shp.SHAPES]
 
 
+def use_host_pods():
+    """Stand the production pods in with 512 CPU devices.  Entry points
+    call this before their first device query; importing this module
+    leaves ``XLA_FLAGS`` alone."""
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
+
 def main():
+    use_host_pods()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

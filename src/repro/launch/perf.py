@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """§Perf hillclimb driver: lower a cell with optimization overrides and
 record roofline terms per iteration (EXPERIMENTS.md §Perf).
 
@@ -59,7 +56,8 @@ def main():
     ap.add_argument("--out", default="results/perf")
     args = ap.parse_args()
 
-    from repro.launch.dryrun import lower_cell
+    from repro.launch.dryrun import lower_cell, use_host_pods
+    use_host_pods()
     out = pathlib.Path(args.out)
     out.mkdir(parents=True, exist_ok=True)
 

@@ -59,7 +59,7 @@ def main():
     pipe = data_mod.Pipeline(dcfg, host_id=jax.process_index(),
                              n_hosts=jax.process_count())
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         p_shard = params_sharding(params, mesh)
         params = jax.device_put(params, p_shard)

@@ -10,24 +10,12 @@ from jax.sharding import PartitionSpec as P
 # ---------------------------------------------------------------------------
 # Sharding: specs are written with logical axes; `shard()` silently drops
 # axes the active mesh doesn't have ("pod" on single-pod runs) and is a
-# no-op outside a mesh context (unit tests on one device).
+# no-op outside a mesh context (unit tests on one device).  The active
+# mesh is the one set by `jax.set_mesh`.
 # ---------------------------------------------------------------------------
 def _active_mesh():
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m.empty:
-            return None
-        return m
-    except Exception:
-        return None
-
-
-def batch_axes(mesh=None):
-    mesh = mesh if mesh is not None else _active_mesh()
-    if mesh is None:
-        return ("data",)
-    return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _axis_size(mesh, s):

@@ -31,7 +31,7 @@ from repro.train.step import make_train_step
 def run_steps(mesh, model, params, opt_state, pipe, opt_cfg, lo, hi):
     step_fn = make_train_step(model, opt_cfg)
     losses = []
-    with mesh:
+    with jax.set_mesh(mesh):
         p_shard = params_sharding(params, mesh)
         params = jax.device_put(params, p_shard)
         jitted = jax.jit(step_fn)

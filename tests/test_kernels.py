@@ -25,7 +25,7 @@ def test_pack_unpack_roundtrip(bits, axis):
 
 @pytest.mark.parametrize("bits", [4, 8])
 @pytest.mark.parametrize("mnk", [(16, 128, 64), (32, 256, 128),
-                                 (128, 128, 512)])
+                                 (128, 128, 512), (64, 256, 1024)])
 def test_quant_matmul_vs_oracle(bits, mnk):
     m, n, k = mnk
     rng = np.random.default_rng(1)
@@ -34,7 +34,7 @@ def test_quant_matmul_vs_oracle(bits, mnk):
     scale = jnp.asarray(rng.uniform(0.001, 0.1, n), jnp.float32)
     wp = ref.pack_bitplanes(w, bits, axis=0)
     got = ops.quant_matmul(a, wp, scale, bits=bits, interpret=True,
-                           block_m=16, block_n=64, block_k=64)
+                           block_m=32, block_n=128, block_k=256)
     want = ref.quant_matmul(a, wp, scale, bits=bits)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6)
@@ -58,6 +58,31 @@ def test_popcount_matmul_vs_oracle(ba, bw):
     np.testing.assert_array_equal(np.asarray(got), want)
     oracle = ref.popcount_matmul(ap, wp, a_signed=True, w_signed=True)
     np.testing.assert_array_equal(np.asarray(oracle), want)
+
+
+def test_popcount_matmul_tiles_k():
+    """K of 8192 runs as two 128-word blocks of the accumulation grid."""
+    m, n, k = 8, 128, 8192
+    rng = np.random.default_rng(5)
+    a = _rand_int(rng, 4, (m, k))
+    w = _rand_int(rng, 4, (k, n))
+    got = ops.popcount_matmul(ref.pack_bitplanes(a, 4, axis=1),
+                              ref.pack_bitplanes(w, 4, axis=0),
+                              interpret=True, block_k=4096)
+    want = np.asarray(a, np.int64) @ np.asarray(w, np.int64)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("dim,target,align,want", [
+    (896, 512, 256, 896),      # qwen2 d_model: no 256-multiple divides
+    (4864, 512, 256, 256),     # qwen2 d_ff
+    (4864, 128, 128, 128),
+    (8, 128, 32, 8),           # smaller than one tile: the full dim
+    (152, 128, 128, 152),      # 4864 bits of packed words
+])
+def test_block_tiles_are_mosaic_aligned(dim, target, align, want):
+    from repro.kernels.bitserial_matmul import _tile
+    assert _tile(dim, target, align) == want
 
 
 def test_popcount_matches_engine_semantics():

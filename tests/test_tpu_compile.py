@@ -1,0 +1,116 @@
+"""Compile the main path for a TPU v5e that is described, not attached.
+
+Nothing runs: each test lowers a kernel or a jitted step at real widths
+and hands it to the TPU compiler installed with JAX, which refuses what
+the chip would refuse (unaligned blocks, operand types Mosaic cannot
+feed the MXU, programs that do not fit the chip's memory).  Results and
+times come only from a chip run (``chip_smoke.py``).
+
+The topology is described inside a module fixture: only the worker that
+runs this file loads the TPU library, and every worker collects the
+same tests.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import configs
+from repro.kernels import bitplane_ops, bitserial_matmul
+from repro.models.model import LM
+
+V5E_HBM_BYTES = 16 * 2**30
+QWEN2_KN = [(896, 4864), (4864, 896), (896, 128)]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache; keep it out of the cache altogether
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+def test_lane_fold_compiles_at_engine_shape(one_chip):
+    """idot4 at 64 blocks of 40 columns folds 57 lanes of 80 words with
+    a 15-bit accumulator -- the fold the engine hands to Pallas."""
+    m, lanes, words = 15, 57, 80
+    assert lanes * words * 32 >= bitplane_ops.PALLAS_FOLD_MIN_COLS
+    x = jax.ShapeDtypeStruct((m, lanes, words), jnp.uint32,
+                             sharding=one_chip)
+    compiled = jax.jit(
+        lambda x: bitplane_ops.lane_fold_pallas(x, m)).lower(x).compile()
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("bits", [4, 8])
+@pytest.mark.parametrize("k,n", QWEN2_KN)
+def test_quant_matmul_compiles(one_chip, k, n, bits):
+    args = _on(one_chip, (jax.ShapeDtypeStruct((8, k), jnp.int8),
+                          jax.ShapeDtypeStruct((bits, k // 32, n), jnp.uint32),
+                          jax.ShapeDtypeStruct((n,), jnp.float32)))
+    compiled = jax.jit(lambda a, w, s: bitserial_matmul.quant_matmul(
+        a, w, s, bits=bits)).lower(*args).compile()
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("k,n", QWEN2_KN)
+def test_popcount_matmul_compiles(one_chip, k, n):
+    args = _on(one_chip, (jax.ShapeDtypeStruct((8, 8, k // 32), jnp.uint32),
+                          jax.ShapeDtypeStruct((4, k // 32, n), jnp.uint32)))
+    compiled = jax.jit(bitserial_matmul.popcount_matmul).lower(
+        *args).compile()
+    assert _has_kernel(compiled)
+
+
+@pytest.fixture(scope="module")
+def qwen2(one_chip):
+    model = LM(configs.get_config("qwen2-0.5b"))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    return model, _on(one_chip, params)
+
+
+def _fits(compiled):
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 0 < used < V5E_HBM_BYTES, used
+
+
+def test_qwen2_decode_step_compiles(one_chip, qwen2):
+    """The serve engine's decode step: 8 slots, 1024-token caches."""
+    model, params = qwen2
+    caches = _on(one_chip, jax.eval_shape(lambda: model.init_cache(8, 1024)))
+    tokens = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    _fits(jax.jit(model.decode_step).lower(
+        params, caches, tokens, pos).compile())
+
+
+def test_qwen2_prefill_compiles(one_chip, qwen2):
+    model, params = qwen2
+    tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=one_chip)
+    _fits(jax.jit(lambda p, t: model.prefill(p, tokens=t, capacity=1024))
+          .lower(params, tokens).compile())
