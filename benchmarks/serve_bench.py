@@ -86,17 +86,15 @@ def _engine_run(model, cfg, params, slots, n_req, max_new, probe=None):
 
 
 def _phase_split(stats: dict) -> dict:
-    """prefill vs decode, cold first decode step vs warm steady state."""
-    warm_steps = max(stats["decode_warm_steps"], 1)
+    """prefill vs decode, from the engine's span counters."""
     return {
         "prefill_us_per_token": round(
             stats["prefill_s"] * 1e6 / max(stats["prefill_tokens"], 1)),
         "decode_us_per_token": round(
             stats["decode_s"] * 1e6 / max(stats["decode_tokens"], 1)),
-        "decode_cold_us_per_step": round(stats["decode_cold_s"] * 1e6),
-        "decode_warm_us_per_step": round(
-            stats["decode_warm_s"] * 1e6 / warm_steps),
-        "decode_warm_steps": stats["decode_warm_steps"],
+        "decode_us_per_step": round(
+            stats["decode_s"] * 1e6 / max(stats["decode_n"], 1)),
+        "decode_steps": stats["decode_n"],
     }
 
 
@@ -238,11 +236,11 @@ def run(print_fn=print, json_path=BENCH_JSON, quick=False,
     print_fn(f"serve/continuous_batching,{us_per_token:.0f},"
              f"us_per_token;requests={len(done)};slots={slots};"
              f"tokens={toks}")
-    print_fn(f"serve/phase_split,{split['decode_warm_us_per_step']},"
-             f"decode_warm_us_per_step;"
+    print_fn(f"serve/phase_split,{split['decode_us_per_step']},"
+             f"decode_us_per_step;"
              f"prefill={split['prefill_us_per_token']};"
              f"decode={split['decode_us_per_token']};"
-             f"cold_step={split['decode_cold_us_per_step']}")
+             f"steps={split['decode_steps']}")
 
     # --- fabric leg: same stream, decode loop on the block grid ---------
     attn = params["unit"]["b0"]["attn"]
@@ -291,8 +289,7 @@ def run(print_fn=print, json_path=BENCH_JSON, quick=False,
             "tokens_bit_identical": identical,
             "us_per_token": round(fdt * 1e6 / max(ftoks, 1)),
             "decode_steps_on_fabric": len(probe.costs),
-            **{k: fsplit[k] for k in ("decode_cold_us_per_step",
-                                      "decode_warm_us_per_step")},
+            "decode_us_per_step": fsplit["decode_us_per_step"],
             "session": straj.report(),
             "probe": probe.report(),
         },

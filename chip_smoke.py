@@ -152,9 +152,8 @@ def serve_phase(cfg, seed: int, *, n_requests: int = 16, max_new: int = 32,
           f"prefill_compiles={st['prefill_compiles']} buckets={buckets} "
           f"kv_empty=True steps={st['steps']} "
           f"prefill_s={st['prefill_s']:.3f} "
-          f"decode_cold_s={st['decode_cold_s']:.3f} "
-          f"decode_warm_s={st['decode_warm_s']:.3f} "
-          f"warm_steps={st['decode_warm_steps']} init_s={t_init:.3f} "
+          f"decode_s={st['decode_s']:.3f} "
+          f"decode_n={st['decode_n']} init_s={t_init:.3f} "
           f"run_s={t_run:.3f} reference_s={t_ref:.3f} "
           f"seconds={_secs(t0):.3f}", flush=True)
 

@@ -50,13 +50,15 @@ def _repeat_kv(k, n_heads):
     return jnp.repeat(k, g, axis=2) if g > 1 else k
 
 
+@jax.named_scope("attention")
 def chunked_attention(q, k, v, pos_q, pos_k, *, causal: bool,
                       window=None, chunk: int = 1024):
     """Online-softmax attention, scanning over KV chunks.
 
     q: (B, Sq, H, hd);  k, v: (B, Sk, H, hd) (KV already repeated);
     pos_q: (B, Sq), pos_k: (B, Sk) int32 (-1 = invalid key slot).
-    Working set per step is O(Sq * chunk), never O(Sk^2).
+    Working set per step is O(Sq * chunk), never O(Sk^2).  Its
+    operations carry the name scope ``attention``.
     """
     b, sq, h, hd = q.shape
     sk = k.shape[1]
@@ -188,48 +190,57 @@ def _kv_read(cache, name):
 
 
 def attn_decode(params, x, cache, cfg, pos, *, window=None):
-    """One-token decode.  x: (B, 1, d); pos: (B,) int32 current position."""
+    """One-token decode.  x: (B, 1, d); pos: (B,) int32 current position.
+    The cache update and the attention (not the q/k/v/o projections)
+    carry the name scope ``attention``."""
     b, s, d = x.shape
     assert s == 1
     positions = pos[:, None]
     q, k, v = _qkv(params, x, x, cfg, positions, positions)
 
-    cap = cache["k"].shape[1]
-    slot = pos % cap                                   # ring buffer
-    bidx = jnp.arange(b)
-    if cfg.kv_quant_bits:
-        kq, ks_ = _kv_quantize(k[:, 0], cfg.kv_quant_bits)
-        vq, vs_ = _kv_quantize(v[:, 0], cfg.kv_quant_bits)
-        new_cache = {
-            "k": cache["k"].at[bidx, slot].set(kq),
-            "v": cache["v"].at[bidx, slot].set(vq),
-            "k_s": cache["k_s"].at[bidx, slot].set(ks_),
-            "v_s": cache["v_s"].at[bidx, slot].set(vs_),
-            "pos": cache["pos"].at[bidx, slot].set(pos),
-        }
-    else:
-        new_cache = {
-            "k": cache["k"].at[bidx, slot].set(k[:, 0].astype(jnp.bfloat16)),
-            "v": cache["v"].at[bidx, slot].set(v[:, 0].astype(jnp.bfloat16)),
-            "pos": cache["pos"].at[bidx, slot].set(pos),
-        }
-    ck = _kv_read(new_cache, "k")
-    cv = _kv_read(new_cache, "v")
-    cp = new_cache["pos"]
+    # the cache write and the attention itself, not the projections
+    with jax.named_scope("attention"):
+        cap = cache["k"].shape[1]
+        slot = pos % cap                               # ring buffer
+        bidx = jnp.arange(b)
+        if cfg.kv_quant_bits:
+            kq, ks_ = _kv_quantize(k[:, 0], cfg.kv_quant_bits)
+            vq, vs_ = _kv_quantize(v[:, 0], cfg.kv_quant_bits)
+            new_cache = {
+                "k": cache["k"].at[bidx, slot].set(kq),
+                "v": cache["v"].at[bidx, slot].set(vq),
+                "k_s": cache["k_s"].at[bidx, slot].set(ks_),
+                "v_s": cache["v_s"].at[bidx, slot].set(vs_),
+                "pos": cache["pos"].at[bidx, slot].set(pos),
+            }
+        else:
+            new_cache = {
+                "k": cache["k"].at[bidx, slot].set(
+                    k[:, 0].astype(jnp.bfloat16)),
+                "v": cache["v"].at[bidx, slot].set(
+                    v[:, 0].astype(jnp.bfloat16)),
+                "pos": cache["pos"].at[bidx, slot].set(pos),
+            }
+        ck = _kv_read(new_cache, "k")
+        cv = _kv_read(new_cache, "v")
+        cp = new_cache["pos"]
 
-    scale = cfg.hd ** -0.5
-    qh = shard(q.astype(jnp.float32) * scale, "batch", None, "model", None)
-    kh = _repeat_kv(ck, cfg.n_heads)
-    vh = _repeat_kv(cv, cfg.n_heads)
-    kh = shard(kh, "batch", None, "model", None)
-    vh = shard(vh, "batch", None, "model", None)
-    s_ = jnp.einsum("bqhd,bchd->bqhc", qh, kh)
-    valid = (cp >= 0)[:, None, :] & (cp[:, None, :] <= positions[:, :, None])
-    if window is not None:
-        valid = valid & (cp[:, None, :] > positions[:, :, None] - window)
-    s_ = jnp.where(valid[:, :, None, :], s_, NEG_INF)
-    p = jax.nn.softmax(s_, axis=-1)
-    out = jnp.einsum("bqhc,bchd->bqhd", p, vh).astype(x.dtype)
+        scale = cfg.hd ** -0.5
+        qh = shard(q.astype(jnp.float32) * scale,
+                   "batch", None, "model", None)
+        kh = _repeat_kv(ck, cfg.n_heads)
+        vh = _repeat_kv(cv, cfg.n_heads)
+        kh = shard(kh, "batch", None, "model", None)
+        vh = shard(vh, "batch", None, "model", None)
+        s_ = jnp.einsum("bqhd,bchd->bqhc", qh, kh)
+        valid = ((cp >= 0)[:, None, :]
+                 & (cp[:, None, :] <= positions[:, :, None]))
+        if window is not None:
+            valid = valid & (cp[:, None, :]
+                             > positions[:, :, None] - window)
+        s_ = jnp.where(valid[:, :, None, :], s_, NEG_INF)
+        p = jax.nn.softmax(s_, axis=-1)
+        out = jnp.einsum("bqhc,bchd->bqhd", p, vh).astype(x.dtype)
     y = jnp.einsum("bshk,hkd->bsd", out, dq(params["wo"]))
     return y, new_cache
 

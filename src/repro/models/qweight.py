@@ -70,14 +70,17 @@ def _quantize_leaf(w, bits: int, stacked: bool = False):
 
 
 def dq(leaf, dtype=jnp.bfloat16):
-    """Dequantize a (possibly) quantized weight leaf."""
+    """Dequantize a (possibly) quantized weight leaf.  Its operations
+    carry the name scope ``dq`` in their metadata."""
     if isinstance(leaf, PackedWeight):
-        w = kref.unpack_bitplanes(leaf.planes, axis=0, signed=True)
-        w = w.astype(jnp.float32) * leaf.scale
-        return w.reshape(leaf.shape).astype(dtype)
+        with jax.named_scope("dq"):
+            w = kref.unpack_bitplanes(leaf.planes, axis=0, signed=True)
+            w = w.astype(jnp.float32) * leaf.scale
+            return w.reshape(leaf.shape).astype(dtype)
     if isinstance(leaf, dict) and "q" in leaf:
-        return (leaf["q"].astype(jnp.float32)
-                * leaf["scale"]).astype(dtype)
+        with jax.named_scope("dq"):
+            return (leaf["q"].astype(jnp.float32)
+                    * leaf["scale"]).astype(dtype)
     return leaf
 
 

@@ -6,6 +6,7 @@ Public surface:
 * :class:`repro.serve.kv.PagedKV` -- paged KV-cache accounting
 * :class:`repro.serve.scheduler.Scheduler` / ``SchedulerConfig``
 * :mod:`repro.serve.loadgen` -- seeded arrivals + latency rollups
+* :class:`repro.serve.spans.Span` -- the engine's host spans and counters
 """
 
 from .engine import Request, ServeEngine          # noqa: F401

@@ -51,7 +51,13 @@ launch with exponential backoff up to ``probe_retries`` times, then
 permanently falls back to the probe's host ``ref`` path
 (``observe_ref``) -- serving keeps producing tokens either way.
 ``step_deadline_ms`` tracks per-step wall-clock deadline misses, and
-``fault_report()`` aggregates the health counters."""
+``fault_report()`` aggregates the health counters.
+
+Observability (docs/serve.md): the step, each admission and the decode
+phase run inside spans (:class:`repro.serve.spans.Span`: a profiler
+annotation ``serve.<name>`` plus ``stats["<name>_s"]`` and
+``stats["<name>_n"]``), and the prefill and cache-merge programs have
+stable names, ``jit_prefill`` and ``jit_merge_slot``."""
 
 from __future__ import annotations
 
@@ -67,6 +73,7 @@ from repro.core.faults import FabricFaultError
 
 from .kv import PagedKV
 from .scheduler import Scheduler, SchedulerConfig
+from .spans import Span, counters
 
 
 # eq=False: identity semantics -- requests live in queues and slots, and
@@ -125,6 +132,23 @@ def _bucket(n: int) -> int:
     return 1 << max(0, int(n - 1).bit_length())
 
 
+@jax.jit
+def merge_slot(caches, one, i):
+    """Write a batch-1 prefill cache ``one`` into slot ``i`` of the
+    engine's ``caches``.  The batch dim is dim 1 for scanned-stack
+    ("unit") caches, dim 0 for unstacked ("rest") layer caches.  ``i``
+    is traced, so one program serves every slot."""
+
+    def merge(path, full, src):
+        keys = [getattr(q, "key", str(q)) for q in path
+                if hasattr(q, "key")]
+        bdim = 1 if "unit" in keys else 0
+        idx = (slice(None),) * bdim + (i,)
+        return full.at[idx].set(src[(slice(None),) * bdim + (0,)])
+
+    return jax.tree_util.tree_map_with_path(merge, caches, one)
+
+
 class ServeEngine:
     """Paged continuous-batching decode over fixed jit shapes.
 
@@ -163,8 +187,11 @@ class ServeEngine:
         self.tokens = np.zeros((batch_slots, 1), np.int32)
         self.caches = model.init_cache(batch_slots, capacity)
         self._decode = jax.jit(model.decode_step)
-        self._prefill_one = jax.jit(
-            lambda p, t: model.prefill(p, tokens=t, capacity=capacity))
+
+        def prefill(p, t):
+            return model.prefill(p, tokens=t, capacity=capacity)
+
+        self._prefill_one = jax.jit(prefill)
         # paged KV pool: default exactly covers the dense per-slot
         # caches (batch_slots x capacity tokens), so in-capacity
         # workloads never feel it; shrink it to model real memory
@@ -185,7 +212,6 @@ class ServeEngine:
         self.seed = seed
         self._base_key = jax.random.PRNGKey(seed)
         self._step_count = 0       # worked steps (sampling-key counter)
-        self._decode_count = 0     # decode launches (cold/warm split)
         self._admit_count = 0
         # prompt-length bucketing: _prefill_one compiles once per padded
         # shape, so tracking the distinct buckets counts its compiles.
@@ -205,15 +231,12 @@ class ServeEngine:
                       "admitted": 0, "rejected": 0, "truncated": 0,
                       "preemptions": 0, "resumes": 0,
                       "stream_prefill_tokens": 0,
-                      # phase timing split (serve_bench artifact): total
-                      # prefill wall-clock + prompt tokens pushed through
-                      # it, and decode wall-clock split cold (first decode
-                      # step: compiles + fabric-session warm-up) vs warm
-                      # (steady state)
-                      "prefill_s": 0.0, "prefill_tokens": 0,
-                      "decode_s": 0.0, "decode_tokens": 0,
-                      "decode_cold_s": 0.0, "decode_warm_s": 0.0,
-                      "decode_warm_steps": 0}
+                      # prompt tokens pushed through prefill, tokens
+                      # produced by decode
+                      "prefill_tokens": 0, "decode_tokens": 0,
+                      # host seconds and count of every span
+                      # (serve/spans.py): prefill_s, decode_s, ...
+                      **counters()}
 
     # -- queue --------------------------------------------------------------
     @property
@@ -274,75 +297,65 @@ class ServeEngine:
     def _prefill_into(self, i: int, req: Request):
         """Admit ``req`` into slot ``i``: bounded prefill call, paged KV
         allocation, and (for long prompts) arming the streamed tail."""
-        tp0 = time.perf_counter()
-        resume = bool(req.out)
-        # a resumed request re-prefills prompt + generated tokens: the
-        # recompute preemption policy (greedy chains continue bit-
-        # identically; see docs/serve.md)
-        seq = (np.concatenate([np.asarray(req.prompt, np.int32),
-                               np.asarray(req.out, np.int32)])
-               if resume else np.asarray(req.prompt, np.int32))
-        seq_len = len(seq)
-        chunk = self.sched.first_chunk(seq_len)
-        if not self.kv.alloc(req.rid, chunk):
-            raise RuntimeError("admission verdict said pages were free")
-        # pad the prefill to a power-of-two bucket: ragged arrival
-        # traffic hits a handful of compiled prefill shapes instead of
-        # one per distinct length.  Pad tokens sit at positions >= the
-        # real length, which decode either masks (cache position >
-        # current pos) or overwrites before ever attending --
-        # bit-identical logits at the real last token.
-        bucket = (min(_bucket(chunk), self.capacity)
-                  if self._pad_safe else chunk)
-        padded = np.zeros((bucket,), np.int32)
-        padded[:chunk] = seq[:chunk]
-        if bucket not in self._prefill_buckets:
-            self._prefill_buckets.add(bucket)
-            self.stats["prefill_compiles"] += 1
-        logits, cache = self._prefill_one(
-            self.params, jnp.asarray(padded)[None, :])
+        with Span(self.stats, "prefill", rid=req.rid) as span:
+            resume = bool(req.out)
+            # a resumed request re-prefills prompt + generated tokens:
+            # the recompute preemption policy (greedy chains continue
+            # bit-identically; see docs/serve.md)
+            seq = (np.concatenate([np.asarray(req.prompt, np.int32),
+                                   np.asarray(req.out, np.int32)])
+                   if resume else np.asarray(req.prompt, np.int32))
+            seq_len = len(seq)
+            chunk = self.sched.first_chunk(seq_len)
+            if not self.kv.alloc(req.rid, chunk):
+                raise RuntimeError("admission verdict said pages were free")
+            # pad the prefill to a power-of-two bucket: ragged arrival
+            # traffic hits a handful of compiled prefill shapes instead
+            # of one per distinct length.  Pad tokens sit at positions
+            # >= the real length, which decode either masks (cache
+            # position > current pos) or overwrites before ever
+            # attending -- bit-identical logits at the real last token.
+            bucket = (min(_bucket(chunk), self.capacity)
+                      if self._pad_safe else chunk)
+            span.annotate(bucket=bucket)
+            if bucket not in self._prefill_buckets:
+                self._prefill_buckets.add(bucket)
+                self.stats["prefill_compiles"] += 1
+            with Span(self.stats, "prefill_launch"):
+                padded = np.zeros((bucket,), np.int32)
+                padded[:chunk] = seq[:chunk]
+                logits, cache = self._prefill_one(
+                    self.params, jnp.asarray(padded)[None, :])
+            with Span(self.stats, "merge"):
+                self.caches = merge_slot(self.caches, cache, np.int32(i))
 
-        # merge this request's cache into slot i: the batch dim is
-        # dim 1 for scanned-stack ("unit") caches, dim 0 for
-        # unstacked ("rest") layer caches.
-        def merge(path, full, one):
-            keys = [getattr(q, "key", str(q)) for q in path
-                    if hasattr(q, "key")]
-            bdim = 1 if "unit" in keys else 0
-            idx = (slice(None),) * bdim + (i,)
-            src = one[(slice(None),) * bdim + (0,)]
-            return full.at[idx].set(src)
-
-        self.caches = jax.tree_util.tree_map_with_path(
-            merge, self.caches, cache)
-
-        now = time.perf_counter()
-        if req.t_admit is None:
-            req.t_admit = now
-        req._admit_seq = self._admit_count
-        self._admit_count += 1
-        req._seq = seq
-        self.slots[i] = req
-        self.pos[i] = chunk
-        self.stats["admitted"] += 1
-        if resume:
-            self.stats["resumes"] += 1
-        if chunk < seq_len:
-            # long prompt: the tail streams through the shared decode
-            # step, one token per engine step, interleaved with the
-            # other lanes' decode
-            req.status = "prefill"
-            req._ptr = chunk
-            self.tokens[i, 0] = seq[chunk]
-        else:
-            req.status = "decode"
-            nxt = int(jnp.argmax(logits[0, chunk - 1]))
-            req.out.append(nxt)
-            if req.t_first is None:
-                req.t_first = now
-            self.tokens[i, 0] = nxt
-        self.stats["prefill_s"] += time.perf_counter() - tp0
-        self.stats["prefill_tokens"] += chunk
+            now = time.perf_counter()
+            if req.t_admit is None:
+                req.t_admit = now
+            req._admit_seq = self._admit_count
+            self._admit_count += 1
+            req._seq = seq
+            self.slots[i] = req
+            self.pos[i] = chunk
+            self.stats["admitted"] += 1
+            if resume:
+                self.stats["resumes"] += 1
+            if chunk < seq_len:
+                # long prompt: the tail streams through the shared
+                # decode step, one token per engine step, interleaved
+                # with the other lanes' decode
+                req.status = "prefill"
+                req._ptr = chunk
+                self.tokens[i, 0] = seq[chunk]
+            else:
+                req.status = "decode"
+                with Span(self.stats, "first_token"):
+                    nxt = int(jnp.argmax(logits[0, chunk - 1]))
+                req.out.append(nxt)
+                if req.t_first is None:
+                    req.t_first = now
+                self.tokens[i, 0] = nxt
+            self.stats["prefill_tokens"] += chunk
 
     # -- retirement / preemption --------------------------------------------
     def _finish(self, i: int, req: Request):
@@ -423,6 +436,10 @@ class ServeEngine:
         """One scheduling step: retire, admit, decode every active lane,
         retire again, and backfill freed slots -- so with work queued
         the batch never runs a lane short.  Returns finished requests."""
+        with Span(self.stats, "step"):
+            return self._step()
+
+    def _step(self) -> List[Request]:
         t0 = time.perf_counter()
         finished = self._retire_satisfied()
         admitted = self._admit()
@@ -433,15 +450,38 @@ class ServeEngine:
         active = [i for i, r in enumerate(self.slots) if r is not None]
         decode_ran = False
         if active:
-            td0 = time.perf_counter()
-            # paged-KV accounting for the token each lane writes this
-            # step; a dry pool preempts the least-committed lane(s)
+            with Span(self.stats, "decode"):
+                finished += self._decode_active(active)
+            decode_ran = True
+
+        # retire-then-backfill: a slot freed THIS step serves the queue
+        # THIS step (its prefill runs now; it decodes next step)
+        admitted += self._admit()
+
+        # unified accounting epilogue: every path that did work -- a
+        # prefill-only turn, a retire-only turn, or a full decode --
+        # counts the step and checks the deadline (the old early return
+        # skipped all of it)
+        if decode_ran or admitted or finished:
+            self._step_count += 1
+            self.stats["steps"] += 1
+            if self.step_deadline_ms is not None:
+                if (time.perf_counter() - t0) * 1e3 > self.step_deadline_ms:
+                    self.stats["deadline_misses"] += 1
+        return finished
+
+    def _decode_active(self, active: List[int]) -> List[Request]:
+        """The decode phase: KV accounting, the probe, one decode launch
+        over every lane, the sampled tokens back on the host, and the
+        per-lane bookkeeping.  Returns the requests it finished."""
+        # paged-KV accounting for the token each lane writes this
+        # step; a dry pool preempts the least-committed lane(s)
+        with Span(self.stats, "kv_append"):
             self._append_kv(active)
-            active = [i for i, r in enumerate(self.slots) if r is not None]
-            streaming = [i for i in active
-                         if self.slots[i].status == "prefill"]
-            if self.fabric_probe is not None and not self.fabric_probe.done \
-                    and not self.probe_fallback:
+        active = [i for i, r in enumerate(self.slots) if r is not None]
+        if self.fabric_probe is not None and not self.fabric_probe.done \
+                and not self.probe_fallback:
+            with Span(self.stats, "probe"):
                 # this step's real activations -- the token embeddings
                 # of the ACTIVE lanes only (a finished slot's stale
                 # token never reaches the grid; the fused program's M
@@ -449,6 +489,7 @@ class ServeEngine:
                 x = self.model._embed(
                     self.params, jnp.asarray(self.tokens[active]))
                 self._observe_guarded(np.asarray(x, np.float32)[:, 0, :])
+        with Span(self.stats, "decode_launch"):
             logits, self.caches = self._decode(
                 self.params, self.caches, jnp.asarray(self.tokens),
                 jnp.asarray(self.pos))
@@ -458,10 +499,13 @@ class ServeEngine:
                     key, logits[:, 0] / self.temperature, axis=-1)
             else:
                 nxt = jnp.argmax(logits[:, 0], axis=-1)
+        with Span(self.stats, "sync"):
             nxt = np.asarray(nxt, np.int32)
 
-            now = time.perf_counter()
-            produced = 0
+        now = time.perf_counter()
+        finished = []
+        produced = 0
+        with Span(self.stats, "lanes"):
             for i in active:
                 req = self.slots[i]
                 self.pos[i] += 1
@@ -483,34 +527,7 @@ class ServeEngine:
                 if len(req.out) >= req.max_new:
                     self._finish(i, req)
                     finished.append(req)
-            # decode phase split: the FIRST decode launch pays the
-            # one-time costs (decode_step jit compile, fabric-session
-            # weight warm-up); later launches are the steady state
-            dt = time.perf_counter() - td0
-            self.stats["decode_s"] += dt
-            self.stats["decode_tokens"] += produced
-            if self._decode_count == 0:
-                self.stats["decode_cold_s"] += dt
-            else:
-                self.stats["decode_warm_s"] += dt
-                self.stats["decode_warm_steps"] += 1
-            self._decode_count += 1
-            decode_ran = True
-
-        # retire-then-backfill: a slot freed THIS step serves the queue
-        # THIS step (its prefill runs now; it decodes next step)
-        admitted += self._admit()
-
-        # unified accounting epilogue: every path that did work -- a
-        # prefill-only turn, a retire-only turn, or a full decode --
-        # counts the step and checks the deadline (the old early return
-        # skipped all of it)
-        if decode_ran or admitted or finished:
-            self._step_count += 1
-            self.stats["steps"] += 1
-            if self.step_deadline_ms is not None:
-                if (time.perf_counter() - t0) * 1e3 > self.step_deadline_ms:
-                    self.stats["deadline_misses"] += 1
+        self.stats["decode_tokens"] += produced
         return finished
 
     def run(self) -> List[Request]:

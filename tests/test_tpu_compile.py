@@ -11,6 +11,8 @@ runs this file loads the TPU library, and every worker collects the
 same tests.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -18,7 +20,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import configs
 from repro.kernels import bitplane_ops, bitserial_matmul
+from repro.models import qweight
 from repro.models.model import LM
+from repro.serve.engine import merge_slot
 
 V5E_HBM_BYTES = 16 * 2**30
 QWEN2_KN = [(896, 4864), (4864, 896), (896, 128)]
@@ -114,3 +118,30 @@ def test_qwen2_prefill_compiles(one_chip, qwen2):
     tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=one_chip)
     _fits(jax.jit(lambda p, t: model.prefill(p, tokens=t, capacity=1024))
           .lower(params, tokens).compile())
+
+
+def test_merge_slot_compiles_at_chat_shape(one_chip, qwen2):
+    """The serve engine's cache merge: one admission into 64 slots of
+    3072-token caches, one program whatever the slot."""
+    model, _ = qwen2
+    caches = _on(one_chip, jax.eval_shape(lambda: model.init_cache(64, 3072)))
+    one = _on(one_chip, jax.eval_shape(lambda: model.init_cache(1, 3072)))
+    i = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    _fits(merge_slot.lower(caches, one, i).compile())
+
+
+def test_w4_decode_step_ops_carry_the_scope_names(one_chip, qwen2):
+    """The compiled program's operations name the ``attention`` and
+    ``dq`` scopes in their metadata: a trace's operation names join
+    them."""
+    model, params = qwen2
+    params = jax.eval_shape(
+        lambda p: qweight.quantize_tree(p, bits=4), params)
+    caches = _on(one_chip, jax.eval_shape(lambda: model.init_cache(8, 1024)))
+    tokens = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    txt = jax.jit(model.decode_step).lower(
+        _on(one_chip, params), caches, tokens, pos).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', txt)
+    assert any("/attention/" in n and "dot_general" in n for n in names)
+    assert any("/dq/" in n for n in names)
