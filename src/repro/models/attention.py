@@ -12,6 +12,9 @@ from .common import dense_init, rope, shard
 from .qweight import dq
 
 NEG_INF = -1e30
+# keeps f32 dot operands in f32: at DEFAULT precision the TPU's MXU takes
+# them as bf16, which rounds decode's query and softmax weights
+F32 = jax.lax.Precision.HIGHEST
 
 
 def attn_init(key, cfg, cross: bool = False) -> dict:
@@ -121,9 +124,9 @@ def attn_apply(params, x, cfg, positions, *, causal=True, window=None,
 
 
 # ---------------------------------------------------------------------------
-# Decode path: ring-buffer KV cache (optionally int8-quantized "storage
-# mode", the Compute RAM dual-mode idea applied to the cache: halves the
-# dominant HBM term of decode -- see EXPERIMENTS.md §Perf)
+# Decode path: ring-buffer KV cache (optionally int8- or 4-bit-quantized
+# "storage mode", the Compute RAM dual-mode idea applied to the cache: it
+# shrinks the dominant HBM term of decode)
 # ---------------------------------------------------------------------------
 def init_kv_cache(cfg, batch: int, capacity: int, window=None) -> dict:
     cap = capacity if window is None else min(capacity, window)
@@ -191,8 +194,12 @@ def _kv_read(cache, name):
 
 def attn_decode(params, x, cache, cfg, pos, *, window=None):
     """One-token decode.  x: (B, 1, d); pos: (B,) int32 current position.
-    The cache update and the attention (not the q/k/v/o projections)
-    carry the name scope ``attention``."""
+
+    Attention is computed per KV group: the H query heads are viewed as
+    (KV, G) with G = H // KV, head h reading group h // G as
+    ``_repeat_kv`` would map it, so K and V are never repeated to the
+    query heads.  The cache update and the attention (not the q/k/v/o
+    projections) carry the name scope ``attention``."""
     b, s, d = x.shape
     assert s == 1
     positions = pos[:, None]
@@ -225,22 +232,20 @@ def attn_decode(params, x, cache, cfg, pos, *, window=None):
         cv = _kv_read(new_cache, "v")
         cp = new_cache["pos"]
 
+        n_kv, hd = ck.shape[2], ck.shape[3]
         scale = cfg.hd ** -0.5
-        qh = shard(q.astype(jnp.float32) * scale,
-                   "batch", None, "model", None)
-        kh = _repeat_kv(ck, cfg.n_heads)
-        vh = _repeat_kv(cv, cfg.n_heads)
-        kh = shard(kh, "batch", None, "model", None)
-        vh = shard(vh, "batch", None, "model", None)
-        s_ = jnp.einsum("bqhd,bchd->bqhc", qh, kh)
-        valid = ((cp >= 0)[:, None, :]
-                 & (cp[:, None, :] <= positions[:, :, None]))
+        qg = (q.astype(jnp.float32) * scale).reshape(b, n_kv, -1, hd)
+        qg = shard(qg, "batch", "model", None, None)
+        ck = shard(ck, "batch", None, "model", None)
+        cv = shard(cv, "batch", None, "model", None)
+        s_ = jnp.einsum("bkgd,bckd->bkgc", qg, ck, precision=F32)
+        valid = (cp >= 0) & (cp <= pos[:, None])
         if window is not None:
-            valid = valid & (cp[:, None, :]
-                             > positions[:, :, None] - window)
-        s_ = jnp.where(valid[:, :, None, :], s_, NEG_INF)
+            valid = valid & (cp > pos[:, None] - window)
+        s_ = jnp.where(valid[:, None, None, :], s_, NEG_INF)
         p = jax.nn.softmax(s_, axis=-1)
-        out = jnp.einsum("bqhc,bchd->bqhd", p, vh).astype(x.dtype)
+        out = jnp.einsum("bkgc,bckd->bkgd", p, cv, precision=F32)
+        out = out.reshape(b, 1, -1, hd).astype(x.dtype)
     y = jnp.einsum("bshk,hkd->bsd", out, dq(params["wo"]))
     return y, new_cache
 

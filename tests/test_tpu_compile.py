@@ -113,6 +113,38 @@ def test_qwen2_decode_step_compiles(one_chip, qwen2):
         params, caches, tokens, pos).compile())
 
 
+def _nbytes(tree):
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("name,slots", [("qwen2-0.5b", 64),
+                                        ("h2o-danube-1.8b", 32)])
+def test_decode_step_never_repeats_the_ring(one_chip, name, slots):
+    """The decode step at the serving benchmark's backlog shape (slots x
+    1024 positions) computes attention per KV group: no f32 array holds
+    the ring repeated to every query head (slots * 1024 * H * hd
+    elements), and the scratch memory stays under what one layer's
+    cache and weights hold."""
+    cfg = configs.get_config(name)
+    model = LM(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    caches = jax.eval_shape(lambda: model.init_cache(slots, 1024))
+    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(model.decode_step).lower(
+        _on(one_chip, params), _on(one_chip, caches), tokens, pos).compile()
+
+    repeated = slots * 1024 * cfg.n_heads * cfg.hd
+    for dims in re.findall(r"f32\[([0-9,]+)\]", compiled.as_text()):
+        n = 1
+        for d in dims.split(","):
+            n *= int(d)
+        assert n != repeated, f"f32[{dims}]"
+    one_layer = (_nbytes(caches["unit"]) + _nbytes(params["unit"])) \
+        // cfg.n_layers
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer
+
+
 def test_qwen2_prefill_compiles(one_chip, qwen2):
     model, params = qwen2
     tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=one_chip)
