@@ -2,7 +2,8 @@
 
 Once the window has closed, a sample of the finished requests is drawn
 from the seed, the request with the longest sequence always in it, until
-it holds ``SAMPLE_TOKENS`` served tokens.  The reference runs once over
+it holds ``SAMPLE_TOKENS`` served tokens.  The plain reference of the
+configuration's architecture (``bench/arch/<arch>.py``) runs once over
 each prompt with its served tokens, and every served token is read as
 the gap between the reference's best logit at its position and the
 reference's logit of the served token.  The widest gap is compared with
@@ -16,8 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from . import reference
 
 SAMPLE_TOKENS = 768
 SAMPLE_MAX = 24
@@ -42,27 +41,28 @@ def sample(done, seed: int):
     return picked
 
 
-def _scorer(cj: dict, rows: int, mode: str):
+def _scorer(arch, cj: dict, rows: int, mode: str):
     @jax.jit
     def score(w, tokens, start, served):
-        ref = reference.logits_rows(cj, w, tokens, start, rows)
+        ref = arch.logits_rows(cj, w, tokens, start, rows, "f32")
         if mode == "program":
             pick = served
         else:
             pick = jnp.argmax(
-                reference.logits_rows(cj, w, tokens, start, rows, mode), -1)
+                arch.logits_rows(cj, w, tokens, start, rows, mode), -1)
         got = jnp.take_along_axis(ref, pick[:, None], -1)[:, 0]
         return jnp.max(ref, axis=-1) - got
     return score
 
 
-def gaps(cj: dict, w: dict, reqs, width: int, rows: int,
+def gaps(arch, cj: dict, w: dict, reqs, width: int, rows: int,
          mode: str = "program") -> np.ndarray:
     """Per served token, the reference's best logit minus the logit of
     the token that ``mode`` puts there: the served token (``program``),
     or the control's argmax (``fp8``).  Sequences are padded to
-    ``width`` (the forward is causal) and ``rows`` bounds the outputs."""
-    score = _scorer(cj, rows, mode)
+    ``width`` (the forward is causal) and ``rows`` bounds the outputs.
+    ``arch`` is the configuration's architecture module."""
+    score = _scorer(arch, cj, rows, mode)
     out = []
     for r in reqs:
         seq = np.concatenate([np.asarray(r.prompt, np.int32),

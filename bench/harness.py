@@ -2,9 +2,11 @@
 result line.
 
 Everything a cell needs is found by name: its entry in
-``BENCHMARK.json``, its configuration file, its traffic mix
-(``bench/traffic/<mix>.json``), its limits (``bench/limits/<cell>.json``)
-and one reader per per-layer metric (``bench/metrics/<metric>.py``).
+``BENCHMARK.json``, its configuration file, the module of the
+configuration's architecture (``bench/arch/<arch>.py``), its traffic
+mix (``bench/traffic/<mix>.json``), its limits
+(``bench/limits/<cell>.json``) and one reader per per-layer metric
+(``bench/metrics/<metric>.py``).
 The system under test is the repo's serving stack, ``ServeEngine``
 driven through ``add`` and ``step``; of the program the benchmark reads
 only its counters (``ServeEngine.stats``), its request timestamps and
@@ -25,7 +27,8 @@ from pathlib import Path
 
 import numpy as np
 
-from . import check, cost, loadgen, pct, peaks, trace_reduce, weights
+from . import arch as archs
+from . import check, loadgen, pct, peaks, trace_reduce, weights
 
 #: after the window, how long requests due in it may still wait for
 #: their first token before they count as never answered
@@ -61,27 +64,8 @@ def resolve(root: Path, spec: dict, workload: str) -> dict:
         "end_to_end": [m for m in spec["end_to_end"] if applies(m)],
         "per_layer": [m for m in spec["per_layer"] if applies(m)],
         "metrics_dir": root / "bench" / "metrics",
+        "arch_dir": root / "bench" / "arch",
     }
-
-
-def model_config(cj: dict):
-    """The serving stack's ``ModelConfig`` from the published keys."""
-    from repro.configs.base import ModelConfig
-
-    if cj["hidden_act"] != "silu":
-        raise ValueError(f"unsupported hidden_act {cj['hidden_act']!r}")
-    window = (cj.get("sliding_window")
-              if cj.get("use_sliding_window", True) else None)
-    return ModelConfig(
-        name=cj["name"], family="dense",
-        n_layers=cj["num_hidden_layers"], d_model=cj["hidden_size"],
-        n_heads=cj["num_attention_heads"],
-        n_kv_heads=cj["num_key_value_heads"],
-        d_ff=cj["intermediate_size"], vocab=cj["vocab_size"],
-        head_dim=cj.get("head_dim"), qkv_bias=cj["attention_bias"],
-        sliding_window=window, rope_theta=float(cj["rope_theta"]),
-        tie_embeddings=cj["tie_word_embeddings"],
-        norm_eps=cj["rms_norm_eps"], mlp_variant="swiglu")
 
 
 def devices(chips: int, require_chip: bool) -> dict:
@@ -150,7 +134,7 @@ class Window:
     def __init__(self, engine, trace: bool):
         self.engine = engine
         self.recs: dict = {}
-        self.steps: list = []      # (t_end, lanes, live_keys) per decode
+        self.steps: list = []      # (t_end, live keys of each lane) per step
         self.queued: list = []     # requests waiting, after each step
         self.lag: list = []
         if trace:
@@ -176,7 +160,7 @@ class Window:
             finished = eng.step()
         now = time.perf_counter()
         with self.span("bench.stamp"):
-            lanes, live = 0, 0
+            lens = []
             for req in [r for r in eng.slots if r is not None] + finished:
                 rec = self.recs[req.rid]
                 new = len(req.out) - len(rec.stamps)
@@ -185,10 +169,9 @@ class Window:
                 # a lane took part in this step's decode unless its only
                 # token came from its admission
                 if new >= 2 or rec.stamps:
-                    lanes += 1
-                    live += len(req.prompt) + len(req.out) - 1
+                    lens.append(len(req.prompt) + len(req.out) - 1)
                 rec.stamps.extend([now] * new)
-            self.steps.append((now, lanes, live))
+            self.steps.append((now, tuple(lens)))
             self.queued.append(len(eng.queue))
         return now
 
@@ -288,13 +271,13 @@ def run(root: Path, spec: dict, workload: str, seed: int, seconds: float,
         controls=(), arrival=None, keep=None) -> dict:
     """One run of ``workload``.  For setting a cell up, not for its
     runs: ``controls`` names lower-precision modes of the reference
-    (``reference.hidden``) to read on the same sample beside the
-    program; ``arrival`` replaces the mix's arrival parameters (a sweep
-    for the knee); ``keep`` (a dict) receives the window's records."""
+    (the architecture's ``logits_rows``) to read on the same sample
+    beside the program; ``arrival`` replaces the mix's arrival
+    parameters (a sweep for the knee); ``keep`` (a dict) receives the
+    window's records."""
     import jax
 
     from repro.launch.cache import enable_compile_cache
-    from repro.models import qweight
     from repro.models.model import LM
     from repro.serve.engine import Request, ServeEngine
 
@@ -308,17 +291,11 @@ def run(root: Path, spec: dict, workload: str, seed: int, seconds: float,
     compiles = _CompileCounter()
 
     # -- set-up: weights on the device, engine, every shape warmed -------
-    cfg = model_config(cj)
-    model = LM(cfg)
+    arch = archs.load(cj, c["arch_dir"])
+    model = LM(arch.model_config(cj))
     key = weights.seed_key(seed)
-    weights.check_layout(jax.eval_shape(model.init, key), weights.layout(cj))
-    if cj["serve"]["weights"] == "w4":
-        make = jax.jit(lambda k: qweight.quantize_tree(
-            weights.make(cj, k), bits=4,
-            names=set(cj["serve"]["w4_leaves"])))
-    else:
-        make = jax.jit(lambda k: weights.make(cj, k))
-    params = jax.block_until_ready(make(key))
+    weights.check_layout(jax.eval_shape(model.init, key), arch.layout(cj))
+    params = jax.block_until_ready(arch.program_weights(cj, key))
     # a mix may reserve fewer, longer slots than the model's default
     slots = mix.get("batch_slots", cj["serve"]["batch_slots"])
     capacity = mix["capacity"]
@@ -368,12 +345,11 @@ def run(root: Path, spec: dict, workload: str, seed: int, seconds: float,
     # -- per-layer metrics (traced run) ----------------------------------
     # everything a reader may take; readers that later cells add find
     # their inputs here, since this file does not change with them
-    ctx = {"cell": cell, "cj": cj, "mix": mix, "stats": stats, "kv": kv,
-           "records": list(win.recs.values()), "t0": t0, "t_end": t_end,
-           "steps": win.steps, "queued": win.queued, "lag": win.lag,
-           "window_compiles": window_compiles, "trace": red, "peaks": pk,
-           "trace_started_at": tracer.started_at,
-           "shape": cost.Shape.from_config(cj), "e2e": e2e,
+    ctx = {"cell": cell, "cj": cj, "arch": arch, "mix": mix, "stats": stats,
+           "kv": kv, "records": list(win.recs.values()), "t0": t0,
+           "t_end": t_end, "steps": win.steps, "queued": win.queued,
+           "lag": win.lag, "window_compiles": window_compiles, "trace": red,
+           "peaks": pk, "trace_started_at": tracer.started_at, "e2e": e2e,
            "memory_peak_bytes": mem_peak}
     metrics = {}
     wanted = c["per_layer"] if trace else c["end_to_end"]
@@ -392,10 +368,9 @@ def run(root: Path, spec: dict, workload: str, seed: int, seconds: float,
     done = [r.req for r in recs if r.req.status == "done"]
     lag = np.asarray(win.lag) * 1e3 if win.lag else np.zeros((1,))
     # share of the decoding lanes' reserved positions that hold a key
-    in_win = [(lanes, live) for t, lanes, live in win.steps
-              if t0 <= t <= t_end and lanes]
-    fill = (sum(lv for _, lv in in_win)
-            / max(1, sum(n for n, _ in in_win) * capacity))
+    in_win = [lens for t, lens in win.steps if t0 <= t <= t_end and lens]
+    fill = (sum(sum(lens) for lens in in_win)
+            / max(1, sum(len(lens) for lens in in_win) * capacity))
     print(f"run: workload={workload} seed={seed} seconds={seconds} "
           f"sent={len(due)} done={len(done)} rejected={rejected} "
           f"unanswered={unanswered} window_compiles={window_compiles} "
@@ -414,10 +389,10 @@ def run(root: Path, spec: dict, workload: str, seed: int, seconds: float,
     del engine, params, win
     gc.collect()
     t_chk = time.perf_counter()
-    w = check_weights(cj, key)
+    w = arch.reference_weights(cj, key)
     rows = int(mix["output_len"]["max"])
-    g = check.gaps(cj, w, sample, capacity, rows)
-    control = {m: check.gaps(cj, w, sample, capacity, rows, m)
+    g = check.gaps(arch, cj, w, sample, capacity, rows)
+    control = {m: check.gaps(arch, cj, w, sample, capacity, rows, m)
                for m in controls}
     del w
     checks, correct = judge(g, rejected, unanswered, limits)
@@ -459,17 +434,6 @@ def judge(g: np.ndarray, rejected: int, unanswered: int, limits: dict):
                and g.size >= limits["min_checked_tokens"]
                and rejected == 0 and unanswered == 0)
     return checks, bool(correct)
-
-
-def check_weights(cj: dict, key):
-    """The benchmark's weights again, made anew from the seed, as the
-    configuration stores them, for the reference."""
-    import jax
-
-    from . import reference
-
-    return jax.jit(lambda k: reference.stored_weights(
-        cj, weights.make(cj, k)))(key)
 
 
 class _Tracer:

@@ -1,7 +1,8 @@
 """decode_roofline: the least time of a decode step at the chip's peaks
-(``bench/cost.py``, ``bench/peaks.py``) over its measured device time,
-in percent, averaged over the steps of the traced slice.  Which bound
-applies goes to standard error."""
+(the architecture's ``decode_step``, ``bench/cost.py``,
+``bench/peaks.py``) over its measured device time, in percent, averaged
+over the steps of the traced slice.  Which bound applies goes to
+standard error."""
 
 import sys
 
@@ -13,13 +14,13 @@ def read(ctx):
     if red is None or pk is None:
         return None
     n, s = red.module("jit_decode_step")
-    steps = [(lanes, live) for _, lanes, live in red.steps if lanes]
+    steps = [lens for _, lens in red.steps if lens]
     if not n or not steps:
         return None
     least, bounds = 0.0, {}
-    for lanes, live in steps:
-        t, bound = cost.least_time(*cost.decode_step(ctx["shape"], lanes,
-                                                     live), pk)
+    for lens in steps:
+        t, bound = cost.least_time(*ctx["arch"].decode_step(ctx["cj"], lens),
+                                   pk)
         least += t
         bounds[bound] = bounds.get(bound, 0) + 1
     print(f"decode_roofline: bound by {bounds}, least time "

@@ -51,3 +51,25 @@ def test_program_readers_read_nothing_without_their_program(metric):
     red = _red({"jit__lambda(3)": [5, 0.1], "jit_decode_step(7)": [9, 1.0]})
     assert _read(metric, trace=red) is None
     assert _read(metric, trace=None) is None
+
+
+def test_decode_readers_cost_each_step_through_the_architecture():
+    import json
+
+    from bench import cost, peaks
+    from bench.arch import dense
+    from checkout import DATA
+
+    cj = json.loads((DATA / "tiny.json").read_text())
+    pk = peaks.peaks_for("TPU v5 lite")
+    red = _red({"jit_decode_step(7)": [2, 0.004]})
+    red.host_s = 0.5
+    # an admitting step with no decoding lane is left out
+    red.steps = [(1.0, (20, 20, 10)), (1.1, ()), (1.2, (21, 21))]
+    ctx = {"trace": red, "peaks": pk, "arch": dense, "cj": cj}
+    steps = [dense.decode_step(cj, lens) for lens in ((20, 20, 10), (21, 21))]
+    least = sum(cost.least_time(f, b, pk)[0] for f, b in steps) / 2
+    assert _read("decode_roofline", **ctx) == pytest.approx(
+        least / 0.002 * 100.0, rel=1e-12)
+    assert _read("mfu.decode", **ctx) == pytest.approx(
+        sum(f for f, _ in steps) / (0.5 * 197e12) * 100.0, rel=1e-12)

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from bench import cost, loadgen, pct, peaks, weights
+from bench.arch import dense
 from checkout import DATA
 
 
@@ -20,11 +21,13 @@ def _tiny(**kw):
 def test_decode_step_cost_bf16_by_hand():
     # d 64, 4 heads of 16, 2 kv heads, d_ff 128, 2 layers, vocab 256,
     # tied head, q/k/v biases
-    s = cost.Shape.from_config(_tiny())
+    cj = _tiny()
     per_layer = (64 * 4 * 16 + 2 * 64 * 2 * 16 + 4 * 16 * 64
                  + 3 * 64 * 128)                       # 36864
-    assert cost.matmul_params(s) == 2 * per_layer + 64 * 256 == 90112
-    flops, nbytes = cost.decode_step(s, lanes=3, live_keys=50)
+    assert dense.matmul_params(dense.Shape.from_config(cj)) \
+        == 2 * per_layer + 64 * 256 == 90112
+    # three lanes attending over 50 live keys in all
+    flops, nbytes = dense.decode_step(cj, [20, 20, 10])
     assert flops == 2 * 90112 * 3 + 4 * 2 * 4 * 16 * 50 == 566272
     weights_b = 2 * (2 * per_layer + 2 * 4 * 64 + 2 * 8 * 16) + 4 * 64 \
         + 2 * 64 * 256                                 # 182016
@@ -34,17 +37,26 @@ def test_decode_step_cost_bf16_by_hand():
 
 
 def test_decode_step_cost_w4_by_hand():
-    s = cost.Shape.from_config(_tiny(
-        tie_word_embeddings=False, attention_bias=False,
-        serve={"weights": "w4"}))
+    cj = _tiny(tie_word_embeddings=False, attention_bias=False,
+               serve={"weights": "w4"})
     # half a byte a weight plus an f32 scale per entry of the last axis
     layer = ((64 * 64 // 2 + 4 * 16) + 2 * (64 * 32 // 2 + 4 * 16)
              + (64 * 64 // 2 + 4 * 64) + 2 * (64 * 128 // 2 + 4 * 128)
              + (128 * 64 // 2 + 4 * 64) + 4 * 2 * 64)  # 20672
     head = 64 * 256 // 2 + 4 * 256
     rows = 3 * 64 // 2 + 4 * 64
-    assert cost.weight_bytes(s, 3) == 2 * layer + 4 * 64 + head + rows \
-        == 51168
+    assert dense.weight_bytes(dense.Shape.from_config(cj), 3) \
+        == 2 * layer + 4 * 64 + head + rows == 51168
+    kv = 2 * 2 * 2 * 16 * 2 * (50 + 3)
+    logits = 2 * 256 * 3
+    assert dense.decode_step(cj, [20, 20, 10])[1] == 51168 + kv + logits
+
+
+@pytest.mark.parametrize("lens", [[48, 1, 1], [17, 17, 16], [1, 1, 48]])
+def test_decode_step_cost_splits_live_keys_over_lanes_alike(lens):
+    # the same 50 live keys over the same three lanes cost the same
+    cj = _tiny()
+    assert dense.decode_step(cj, lens) == dense.decode_step(cj, [20, 20, 10])
 
 
 def test_least_time_names_its_bound():
@@ -122,15 +134,14 @@ def test_percentile_is_linear_between_order_statistics():
 def test_weight_layout_matches_the_serving_stack():
     import jax
 
-    from bench import harness
     from repro.models.model import LM
 
     for name in ("tiny", "tiny-w4"):
         cj = json.loads((DATA / f"{name}.json").read_text())
-        model = LM(harness.model_config(cj))
+        model = LM(dense.model_config(cj))
         weights.check_layout(jax.eval_shape(model.init, weights.seed_key(0)),
-                             weights.layout(cj))
-    bad = dict(weights.layout(cj))
+                             dense.layout(cj))
+    bad = dict(dense.layout(cj))
     bad.pop(("head",))
     with pytest.raises(ValueError, match="missing"):
         weights.check_layout(jax.eval_shape(model.init, weights.seed_key(0)),

@@ -36,6 +36,7 @@ class Reduction:
     ops: dict              # operation name -> device seconds
     gaps: list             # [(host activity, seconds)]: the longest
     host_s: float = 0.0    # the slice on the benchmark's clock
+    # the window's steps in the slice: (t_end, live keys of each lane)
     steps: list = dataclasses.field(default_factory=list)
 
     def module(self, prefix: str):
