@@ -2,7 +2,7 @@
 
 import importlib
 
-from .base import ModelConfig, MoESpec, RGLRUSpec, SSMSpec  # noqa
+from .base import ModelConfig, MoESpec, RGLRUSpec, SSMSpec, YarnSpec  # noqa
 
 ARCHS = {
     "falcon-mamba-7b": "falcon_mamba_7b",
@@ -15,6 +15,7 @@ ARCHS = {
     "recurrentgemma-9b": "recurrentgemma_9b",
     "chameleon-34b": "chameleon_34b",
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

@@ -19,6 +19,24 @@ class MoESpec:
     # axis, so only an all-to-all-sized reshard remains (EXPERIMENTS.md
     # §Perf iteration 1).
     dispatch_chunks: int = 1
+    # DeepSeek-style expert layer: ``n_shared`` always-on experts of
+    # width ``n_shared * d_ff`` (one SwiGLU) added for every token; the
+    # top-k gates renormalised or not; ``dropless``: every routed token
+    # is computed (sorted by expert, one grouped product) in place of
+    # the capacity dispatch above
+    n_shared: int = 0
+    norm_topk: bool = True
+    dropless: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnSpec:                  # YaRN rotary scaling (DeepSeek-V2)
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,6 +80,17 @@ class ModelConfig:
     # modality frontend stub: inputs arrive as precomputed embeddings
     # ("frames"/"patches") concatenated with token embeddings.
     frontend_stub: Optional[str] = None    # None | "patch" | "frame"
+    # multi-head latent attention (DeepSeek-V2): set ``kv_lora_rank`` to
+    # cache one normalised latent of this width and one rotary key of
+    # ``qk_rope_head_dim`` per position, shared by all heads
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[YarnSpec] = None
+    # leading layers with a dense MLP of width ``d_ff`` before the
+    # expert layers (DeepSeek's ``first_k_dense_replace``)
+    first_dense_layers: int = 0
 
     @property
     def hd(self) -> int:
@@ -69,6 +98,10 @@ class ModelConfig:
 
     # ---- layer plan for scan-over-layers ---------------------------------
     def layer_types(self) -> List[str]:
+        """``dense``: attention and a dense MLP in an expert model."""
+        if self.first_dense_layers:
+            k = self.first_dense_layers
+            return ["dense"] * k + ["attn"] * (self.n_layers - k)
         if self.ssm is not None:
             return ["ssm"] * self.n_layers
         if self.rglru is not None:
@@ -78,8 +111,9 @@ class ModelConfig:
         return ["attn"] * self.n_layers
 
     def scan_plan(self) -> Tuple[List[str], int, List[str]]:
-        """(repeating unit, repeat count, remainder) for lax.scan."""
-        types = self.layer_types()
+        """(repeating unit, repeat count, remainder) for lax.scan, of
+        the layers after the leading dense ones."""
+        types = self.layer_types()[self.first_dense_layers:]
         if self.rglru is not None:
             unit = ["rec", "rec", "attn"]
             n = len(types) // 3
@@ -100,13 +134,21 @@ class ModelConfig:
     def param_count(self) -> int:
         d, hd = self.d_model, self.hd
         n_attn = d * (self.n_heads * hd) * 2 + d * (self.n_kv_heads * hd) * 2
+        if self.kv_lora_rank:
+            r, h = self.kv_lora_rank, self.n_heads
+            rope, nope = self.qk_rope_head_dim, self.qk_nope_head_dim
+            n_attn = (d * h * (nope + rope) + d * (r + rope) + r
+                      + r * h * (nope + self.v_head_dim)
+                      + h * self.v_head_dim * d)
         nmat = 3 if self.mlp_variant == "swiglu" else 2
+        n_dense = nmat * d * self.d_ff
         if self.moe:
-            n_ffn = self.moe.num_experts * 3 * d * self.moe.d_ff \
-                + d * self.moe.num_experts
+            n_ffn = (self.moe.num_experts + self.moe.n_shared) * 3 * d \
+                * self.moe.d_ff + d * self.moe.num_experts
         else:
-            n_ffn = nmat * d * self.d_ff
-        per_layer = {"attn": n_attn + n_ffn, "ssm": 0, "rec": 0}
+            n_ffn = n_dense
+        per_layer = {"attn": n_attn + n_ffn, "dense": n_attn + n_dense,
+                     "ssm": 0, "rec": 0}
         if self.ssm:
             di = self.ssm.expand * d
             dtr = self.ssm.dt_rank or -(-d // 16)
@@ -128,8 +170,9 @@ class ModelConfig:
         if not self.moe:
             return self.param_count()
         full = self.param_count()
-        moe_all = self.n_layers * self.moe.num_experts * 3 * self.d_model \
+        n_moe = self.n_layers - self.first_dense_layers
+        moe_all = n_moe * self.moe.num_experts * 3 * self.d_model \
             * self.moe.d_ff
-        moe_active = self.n_layers * self.moe.top_k * 3 * self.d_model \
+        moe_active = n_moe * self.moe.top_k * 3 * self.d_model \
             * self.moe.d_ff
         return int(full - moe_all + moe_active)

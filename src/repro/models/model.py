@@ -53,13 +53,16 @@ def mlp_apply(params, x):
 # Blocks (one per layer type)
 # ---------------------------------------------------------------------------
 def _block_init(key, cfg: ModelConfig, btype: str):
+    """``dense`` is an attention layer with a dense MLP in a model whose
+    other layers hold experts."""
     d = cfg.d_model
     ks = common.split_keys(key, 4)
     p = {"ln1": jnp.zeros((d,), jnp.float32)}
-    if btype == "attn":
-        p["attn"] = attn.attn_init(ks[0], cfg)
+    if btype in ("attn", "dense"):
+        init = attn.mla_init if cfg.kv_lora_rank else attn.attn_init
+        p["attn"] = init(ks[0], cfg)
         p["ln2"] = jnp.zeros((d,), jnp.float32)
-        if cfg.moe is not None:
+        if cfg.moe is not None and btype == "attn":
             p["moe"] = moe_mod.moe_init(ks[1], cfg)
         elif cfg.d_ff > 0:
             p["mlp"] = mlp_init(ks[1], cfg)
@@ -87,24 +90,39 @@ def _window_for(cfg, btype):
 
 
 def _ffn(params, cfg, x):
+    """(y, aux, counts); counts (``moe_dropless``) is empty elsewhere."""
     if "moe" in params:
+        if cfg.moe.dropless:
+            return moe_mod.moe_dropless(params["moe"], x, cfg)
         y, aux = moe_mod.moe_apply(params["moe"], x, cfg)
-        return y, aux
+        return y, aux, {}
     if "mlp" in params:
-        return mlp_apply(params["mlp"], x), 0.0
-    return None, 0.0
+        return mlp_apply(params["mlp"], x), 0.0, {}
+    return None, 0.0, {}
 
 
 def _block_apply(params, h, cfg, btype, positions, mode, cache,
                  enc_out=None, enc_pos=None, causal=True):
-    """Returns (h, new_cache, aux)."""
+    """Returns (h, new_cache, aux, counts)."""
     new_cache = {}
     aux = 0.0
+    counts = {}
     x = rmsnorm(h, params["ln1"], cfg.norm_eps)
 
-    if btype in ("attn", "xattn"):
+    if btype in ("attn", "dense", "xattn"):
         window = _window_for(cfg, btype)
-        if mode == "decode":
+        if cfg.kv_lora_rank:                 # latent attention (MLA)
+            if mode == "decode":
+                y, new_cache["kv"] = attn.mla_decode(
+                    params["attn"], x, cache["kv"], cfg, positions[:, 0])
+            else:
+                y = attn.mla_apply(params["attn"], x, cfg, positions,
+                                   causal=causal)
+                if mode == "prefill":
+                    cap = cache["kv"]["c"].shape[1]
+                    new_cache["kv"] = attn.prefill_mla_cache(
+                        params["attn"], x, cfg, positions, cap)
+        elif mode == "decode":
             pos = positions[:, 0]
             y, new_cache["kv"] = attn.attn_decode(
                 params["attn"], x, cache["kv"], cfg, pos, window=window)
@@ -123,7 +141,7 @@ def _block_apply(params, h, cfg, btype, positions, mode, cache,
                                 kv_positions=enc_pos)
             h = h + y
         f = rmsnorm(h, params["ln2"], cfg.norm_eps)
-        y, aux = _ffn(params, cfg, f)
+        y, aux, counts = _ffn(params, cfg, f)
         if y is not None:
             h = h + y
 
@@ -141,15 +159,21 @@ def _block_apply(params, h, cfg, btype, positions, mode, cache,
             new_cache["rec"] = c
         h = h + y
         f = rmsnorm(h, params["ln2"], cfg.norm_eps)
-        y, _ = _ffn(params, cfg, f)
+        y, _, _ = _ffn(params, cfg, f)
         if y is not None:
             h = h + y
 
-    return h, new_cache, aux
+    return h, new_cache, aux, counts
+
+
+def _add_counts(a: dict, b: dict) -> dict:
+    return {k: a.get(k, 0) + b.get(k, 0) for k in {**a, **b}}
 
 
 def _block_cache(cfg, btype, batch, capacity):
-    if btype in ("attn", "xattn"):
+    if cfg.kv_lora_rank and btype in ("attn", "dense"):
+        return {"kv": attn.init_mla_cache(cfg, batch, capacity)}
+    if btype in ("attn", "dense", "xattn"):
         window = _window_for(cfg, btype)
         return {"kv": attn.init_kv_cache(cfg, batch, capacity, window)}
     if btype == "ssm":
@@ -167,6 +191,9 @@ class LM:
 
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
+        # leading layers (DeepSeek's dense first layer), run one by one
+        # before the scanned unit
+        self.lead = cfg.layer_types()[:cfg.first_dense_layers]
         self.unit, self.n_units, self.rest = cfg.scan_plan()
         if cfg.is_encdec:
             # decoder layers are xattn; encoder handled separately
@@ -192,6 +219,11 @@ class LM:
             kk = common.split_keys(k_rest, len(self.rest))
             params["rest"] = [
                 _block_init(kk[i], cfg, t) for i, t in enumerate(self.rest)]
+        if self.lead:
+            kk = common.split_keys(jax.random.fold_in(key, 5),
+                                   len(self.lead))
+            params["lead"] = [
+                _block_init(kk[i], cfg, t) for i, t in enumerate(self.lead)]
         if not cfg.tie_embeddings:
             params["head"] = dense_init(k_head, (cfg.d_model, cfg.vocab))
         if cfg.is_encdec:
@@ -215,13 +247,16 @@ class LM:
             lp, lc = xs
             new_lc = {}
             aux = 0.0
+            counts = {}
             for i, t in enumerate(unit):
                 c_i = lc[f"b{i}"] if lc is not None else None
-                hh, nc, a = _block_apply(lp[f"b{i}"], hh, cfg, t, positions,
-                                         mode, c_i, enc_out, enc_pos, causal)
+                hh, nc, a, n = _block_apply(lp[f"b{i}"], hh, cfg, t,
+                                            positions, mode, c_i, enc_out,
+                                            enc_pos, causal)
                 new_lc[f"b{i}"] = nc
                 aux = aux + a
-            return hh, (new_lc, aux)
+                counts = _add_counts(counts, n)
+            return hh, (new_lc, aux, counts)
 
         if mode == "train" and cfg.remat_policy != "none":
             # remat each scanned layer: activation memory stays O(L * B*S*d)
@@ -234,8 +269,8 @@ class LM:
             else:
                 body = jax.checkpoint(body)
         xs = (stacked, caches)
-        h, (new_caches, auxs) = jax.lax.scan(body, h, xs)
-        return h, new_caches, jnp.sum(auxs)
+        h, (new_caches, auxs, counts) = jax.lax.scan(body, h, xs)
+        return h, new_caches, jnp.sum(auxs), jax.tree.map(jnp.sum, counts)
 
     def _embed(self, params, tokens=None, embeds=None):
         if embeds is not None:
@@ -256,8 +291,9 @@ class LM:
         h = embeds
 
         def body(hh, lp):
-            hh, _, _ = _block_apply(lp["b0"], hh, self.cfg, "attn",
-                                    positions, "train", None, causal=False)
+            hh, _, _, _ = _block_apply(lp["b0"], hh, self.cfg, "attn",
+                                       positions, "train", None,
+                                       causal=False)
             return hh, None
 
         h, _ = jax.lax.scan(jax.checkpoint(body), h, enc["unit"])
@@ -273,28 +309,42 @@ class LM:
             positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32),
                                          (b, s))
         h = self._embed(params, tokens, embeds)
+        new_lead, lead_aux, counts = [], 0.0, {}
+        for i, t in enumerate(self.lead):
+            c_i = caches["lead"][i] if caches is not None else None
+            h, nc, a, n = _block_apply(params["lead"][i], h, cfg, t,
+                                       positions, mode, c_i)
+            new_lead.append(nc)
+            lead_aux = lead_aux + a
+            counts = _add_counts(counts, n)
         unit_caches = caches["unit"] if caches is not None else None
-        h, new_unit_caches, aux = self._run_unit(
+        h, new_unit_caches, aux, n = self._run_unit(
             params["unit"], h, positions, mode, unit_caches,
             enc_out=enc_out, enc_pos=enc_pos)
+        aux = aux + lead_aux if self.lead else aux
+        counts = _add_counts(counts, n)
         new_rest = []
         if self.rest:
             for i, t in enumerate(self.rest):
                 c_i = caches["rest"][i] if caches is not None else None
-                h, nc, a = _block_apply(params["rest"][i], h, cfg, t,
-                                        positions, mode, c_i,
-                                        enc_out, enc_pos)
+                h, nc, a, n = _block_apply(params["rest"][i], h, cfg, t,
+                                           positions, mode, c_i,
+                                           enc_out, enc_pos)
                 new_rest.append(nc)
                 aux = aux + a
+                counts = _add_counts(counts, n)
         logits = self._head(params, h)
-        new_caches = ({"unit": new_unit_caches, "rest": new_rest}
-                      if mode != "train" else None)
-        return logits, new_caches, aux
+        new_caches = None
+        if mode != "train":
+            new_caches = {"unit": new_unit_caches, "rest": new_rest}
+            if self.lead:
+                new_caches["lead"] = new_lead
+        return logits, new_caches, aux, counts
 
     def apply(self, params, tokens=None, embeds=None, positions=None,
               enc_out=None, enc_pos=None):
-        logits, _, aux = self._forward(params, tokens, embeds, positions,
-                                       "train", None, enc_out, enc_pos)
+        logits, _, aux, _ = self._forward(params, tokens, embeds, positions,
+                                          "train", None, enc_out, enc_pos)
         return logits, aux
 
     @property
@@ -320,26 +370,43 @@ class LM:
             if self.n_units > 1 else x[None],
             one_unit(None))
         rest = [ _block_cache(cfg, t, batch, capacity) for t in self.rest ]
-        return {"unit": unit_cache, "rest": rest}
+        caches = {"unit": unit_cache, "rest": rest}
+        if self.lead:
+            caches["lead"] = [_block_cache(cfg, t, batch, capacity)
+                              for t in self.lead]
+        return caches
 
     def prefill(self, params, tokens=None, embeds=None, capacity=None,
                 enc_out=None, enc_pos=None):
         s = (tokens if tokens is not None else embeds).shape[1]
         b = (tokens if tokens is not None else embeds).shape[0]
         caches = self.init_cache(b, capacity or s)
-        logits, caches, _ = self._forward(params, tokens, embeds, None,
-                                          "prefill", caches,
-                                          enc_out, enc_pos)
+        logits, caches, _, _ = self._forward(params, tokens, embeds, None,
+                                             "prefill", caches,
+                                             enc_out, enc_pos)
         return logits, caches
 
+    @property
+    def counters(self) -> tuple:
+        """Names of the counters ``decode_step(..., counts=True)``
+        returns: the dropless expert layer's, summed over its layers."""
+        if self.cfg.moe is not None and self.cfg.moe.dropless:
+            return ("moe_experts_hit", "moe_layer_steps", "moe_dropped")
+        return ()
+
     def decode_step(self, params, caches, tokens, pos,
-                    enc_out=None, enc_pos=None):
-        """tokens: (B, 1); pos: (B,) int32."""
+                    enc_out=None, enc_pos=None, counts=False):
+        """tokens: (B, 1); pos: (B,) int32.  With ``counts`` it returns
+        (logits, caches, int32 vector of ``counters``) as well."""
         positions = pos[:, None]
-        logits, new_caches, _ = self._forward(
+        logits, new_caches, _, n = self._forward(
             params, tokens, None, positions, "decode", caches,
             enc_out, enc_pos)
-        return logits, new_caches
+        if not counts:
+            return logits, new_caches
+        n_moe = self.cfg.n_layers - self.cfg.first_dense_layers
+        vec = jnp.stack([n["experts_hit"], jnp.int32(n_moe), n["dropped"]])
+        return logits, new_caches, vec
 
     # -- loss -----------------------------------------------------------------
     def loss(self, params, batch):

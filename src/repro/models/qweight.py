@@ -11,6 +11,11 @@ idea applied at model scale).
 ``dq(leaf)`` transparently expands either form (or passes raw arrays
 through) at the point of use; XLA fuses the dequant into the consuming
 matmul so no expanded copy lives in HBM.
+
+An expert stack (``w_gate``/``w_up``/``w_down`` directly under ``moe``,
+(E, in, out) a layer) keeps its expert axis in front of the planes and
+the scales: ``planes`` (E, 4, in//32, out), ``scale`` (E, out), one scale
+per expert and output channel.
 """
 
 from __future__ import annotations
@@ -23,7 +28,10 @@ from repro.kernels import ref as kref
 # weights worth quantizing (2D+ matmul operands)
 _QUANT_NAMES = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                 "in_proj", "out_proj", "x_proj", "dt_w", "wx", "wy",
-                "wi", "wr", "out", "embed", "head"}
+                "wi", "wr", "out", "embed", "head",
+                "wkv_a", "wk_b", "wv_b"}
+#: expert stacks: these names directly under a ``moe`` node
+_EXPERT_NAMES = {"w_gate", "w_up", "w_down"}
 
 
 @jax.tree_util.register_pytree_node_class
@@ -43,30 +51,28 @@ class PackedWeight:
         return cls(leaves[0], leaves[1], shape)
 
 
-def _quantize_leaf(w, bits: int, stacked: bool = False):
+def _quantize_leaf(w, bits: int, stacked: bool = False,
+                   experts: bool = False):
     """``stacked``: leading dim is the scan-layer axis -- every produced
-    leaf keeps it so lax.scan can slice per layer."""
+    leaf keeps it so lax.scan can slice per layer.  ``experts``: the
+    next dim is the expert axis, kept in front of planes and scales."""
     wf = w.astype(jnp.float32)
     qmax = (1 << (bits - 1)) - 1
-    if stacked:
-        flat = wf.reshape(wf.shape[0], -1, wf.shape[-1])    # (L, K, N)
-        amax = jnp.maximum(jnp.max(jnp.abs(flat), axis=1), 1e-8)
-        scale = (amax / qmax).astype(jnp.float32)           # (L, N)
-        q = jnp.clip(jnp.round(flat / scale[:, None, :]), -qmax - 1, qmax)
-        if bits == 4 and flat.shape[1] % 32 == 0:
-            planes = jax.vmap(
-                lambda qq: kref.pack_bitplanes(qq.astype(jnp.int8), 4,
-                                               axis=0))(q)  # (L,4,K/32,N)
-            return PackedWeight(planes, scale, w.shape[1:])
-        return {"q": q.astype(jnp.int8).reshape(w.shape), "scale": scale}
-    flat = wf.reshape(-1, wf.shape[-1])
-    amax = jnp.maximum(jnp.max(jnp.abs(flat), axis=0), 1e-8)
-    scale = (amax / qmax).astype(jnp.float32)
-    q = jnp.clip(jnp.round(flat / scale), -qmax - 1, qmax)
-    if bits == 4 and flat.shape[0] % 32 == 0:
-        planes = kref.pack_bitplanes(q.astype(jnp.int8), 4, axis=0)
-        return PackedWeight(planes, scale, w.shape)
-    return {"q": q.astype(jnp.int8).reshape(w.shape), "scale": scale}
+    lead = int(stacked) + int(experts)
+    flat = wf.reshape(wf.shape[:lead] + (-1, wf.shape[-1]))  # (..., K, N)
+    amax = jnp.maximum(jnp.max(jnp.abs(flat), axis=-2), 1e-8)
+    scale = (amax / qmax).astype(jnp.float32)               # (..., N)
+    q = jnp.clip(jnp.round(flat / scale[..., None, :]), -qmax - 1, qmax)
+    if bits == 4 and flat.shape[-2] % 32 == 0:
+        pack = lambda qq: kref.pack_bitplanes(qq.astype(jnp.int8), 4, axis=0)
+        for _ in range(lead):
+            pack = jax.vmap(pack)
+        # planes (..., 4, K/32, N); shape is one layer's
+        return PackedWeight(pack(q), scale, w.shape[1:] if stacked
+                            else w.shape)
+    # an expert stack's scales broadcast against its (E, K, N) layers
+    return {"q": q.astype(jnp.int8).reshape(w.shape),
+            "scale": scale[..., None, :] if experts else scale}
 
 
 def dq(leaf, dtype=jnp.bfloat16):
@@ -74,6 +80,13 @@ def dq(leaf, dtype=jnp.bfloat16):
     carry the name scope ``dq`` in their metadata."""
     if isinstance(leaf, PackedWeight):
         with jax.named_scope("dq"):
+            if leaf.planes.ndim == 4:
+                # an expert stack, one expert at a time: expanded whole,
+                # the unpacking's intermediates take 8 bytes a weight
+                return jax.lax.map(
+                    lambda ps: dq(PackedWeight(ps[0], ps[1],
+                                               leaf.shape[1:]), dtype),
+                    (leaf.planes, leaf.scale))
             w = kref.unpack_bitplanes(leaf.planes, axis=0, signed=True)
             w = w.astype(jnp.float32) * leaf.scale
             return w.reshape(leaf.shape).astype(dtype)
@@ -87,19 +100,21 @@ def dq(leaf, dtype=jnp.bfloat16):
 def quantize_tree(params, bits: int = 8, names=None):
     """Quantize matching 2D+ weight leaves of a params pytree.
 
-    Leaves under a scanned "unit" stack keep their leading layer axis.
+    Leaves under a scanned "unit" stack keep their leading layer axis;
+    expert stacks keep their expert axis too.
     """
     names = names or _QUANT_NAMES
 
-    def walk(tree, stacked=False):
+    def walk(tree, stacked=False, moe=False):
         if isinstance(tree, dict):
             out = {}
             for k, v in tree.items():
-                min_nd = 3 if stacked else 2
+                experts = moe and k in _EXPERT_NAMES
+                min_nd = (3 if stacked else 2) + experts
                 if k in names and hasattr(v, "ndim") and v.ndim >= min_nd:
-                    out[k] = _quantize_leaf(v, bits, stacked)
+                    out[k] = _quantize_leaf(v, bits, stacked, experts)
                 else:
-                    out[k] = walk(v, stacked or k == "unit")
+                    out[k] = walk(v, stacked or k == "unit", k == "moe")
             return out
         if isinstance(tree, list):
             return [walk(v, stacked) for v in tree]

@@ -57,7 +57,11 @@ Observability (docs/serve.md): the step, each admission and the decode
 phase run inside spans (:class:`repro.serve.spans.Span`: a profiler
 annotation ``serve.<name>`` plus ``stats["<name>_s"]`` and
 ``stats["<name>_n"]``), and the prefill and cache-merge programs have
-stable names, ``jit_prefill`` and ``jit_merge_slot``."""
+stable names, ``jit_prefill`` and ``jit_merge_slot``.  A model with
+device counters (``LM.counters``: the dropless expert layer's
+``moe_experts_hit``, ``moe_layer_steps`` and ``moe_dropped``) returns
+them from its decode step; they come back in the same transfer as the
+sampled tokens and are summed into ``stats``."""
 
 from __future__ import annotations
 
@@ -186,7 +190,15 @@ class ServeEngine:
         self.pos = np.zeros((batch_slots,), np.int32)
         self.tokens = np.zeros((batch_slots, 1), np.int32)
         self.caches = model.init_cache(batch_slots, capacity)
-        self._decode = jax.jit(model.decode_step)
+        self._counters = tuple(getattr(model, "counters", ()))
+        if self._counters:
+            def decode_step(params, caches, tokens, pos):
+                return model.decode_step(params, caches, tokens, pos,
+                                         counts=True)
+
+            self._decode = jax.jit(decode_step)
+        else:
+            self._decode = jax.jit(model.decode_step)
 
         def prefill(p, t):
             return model.prefill(p, tokens=t, capacity=capacity)
@@ -236,7 +248,9 @@ class ServeEngine:
                       "prefill_tokens": 0, "decode_tokens": 0,
                       # host seconds and count of every span
                       # (serve/spans.py): prefill_s, decode_s, ...
-                      **counters()}
+                      **counters(),
+                      # the model's device counters, over decode steps
+                      **{name: 0 for name in self._counters}}
 
     # -- queue --------------------------------------------------------------
     @property
@@ -490,7 +504,7 @@ class ServeEngine:
                     self.params, jnp.asarray(self.tokens[active]))
                 self._observe_guarded(np.asarray(x, np.float32)[:, 0, :])
         with Span(self.stats, "decode_launch"):
-            logits, self.caches = self._decode(
+            logits, self.caches, *dev_counts = self._decode(
                 self.params, self.caches, jnp.asarray(self.tokens),
                 jnp.asarray(self.pos))
             if self.temperature > 0:
@@ -500,6 +514,11 @@ class ServeEngine:
             else:
                 nxt = jnp.argmax(logits[:, 0], axis=-1)
         with Span(self.stats, "sync"):
+            if dev_counts:
+                # one transfer: the tokens and the counters together
+                nxt, dev_counts = jax.device_get((nxt, dev_counts[0]))
+                for name, v in zip(self._counters, dev_counts):
+                    self.stats[name] += int(v)
             nxt = np.asarray(nxt, np.int32)
 
         now = time.perf_counter()

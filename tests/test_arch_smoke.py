@@ -106,6 +106,7 @@ def test_param_counts_plausible():
         "recurrentgemma-9b": (7e9, 11e9),
         "chameleon-34b": (30e9, 38e9),
         "seamless-m4t-large-v2": (1.2e9, 3e9),
+        "deepseek-v2-lite": (15e9, 16.5e9),
     }
     for arch, (lo, hi) in expect.items():
         cfg = configs.get_config(arch)

@@ -177,3 +177,27 @@ def test_w4_decode_step_ops_carry_the_scope_names(one_chip, qwen2):
     names = re.findall(r'op_name="([^"]*)"', txt)
     assert any("/attention/" in n and "dot_general" in n for n in names)
     assert any("/dq/" in n for n in names)
+
+
+def test_deepseek_w4_decode_step_fits_with_its_scopes(one_chip):
+    """DeepSeek-V2-Lite at published widths, every matrix at 4 bits: the
+    engine's counted decode step at the backlog shape (32 slots x 1024
+    positions of latent cache) fits the chip with its output caches,
+    its expert products lower to the grouped-matmul kernel, and the
+    ``mla`` and ``moe`` scopes reach the operations' metadata."""
+    model = LM(configs.get_config("deepseek-v2-lite"))
+    params = jax.eval_shape(lambda k: qweight.quantize_tree(
+        model.init(k), bits=4), jax.random.PRNGKey(0))
+    caches = _on(one_chip, jax.eval_shape(lambda: model.init_cache(32, 1024)))
+    tokens = jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, c, t, s: model.decode_step(p, c, t, s, counts=True)
+    ).lower(_on(one_chip, params), caches, tokens, pos).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        + mem.temp_size_in_bytes < 14 * 2**30
+    assert _has_kernel(compiled)
+    names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    assert any("/mla/" in n for n in names)
+    assert any("/moe/" in n for n in names)
