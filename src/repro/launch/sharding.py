@@ -93,15 +93,19 @@ def batch_sharding(batch, mesh):
 
 def cache_sharding(cache, mesh):
     """Decode caches: (stack, B, ...) -> batch on dim 1, heads/features on
-    the model axis where divisible."""
+    the model axis where divisible.  The dense KV ring is head-major,
+    ``k``/``v`` (B, KV, cap, hd) with scales (B, KV, cap), so its KV
+    axis, dim 1 after the batch, takes the model axis.  The serve
+    engine's decode and merge programs donate the cache and update it in
+    place."""
     def one(path, leaf):
         keys = [getattr(p, "key", getattr(p, "name", str(p))) for p in path]
         name = keys[-1]
         stack = (None,) if "unit" in keys else ()   # scanned stacks only
-        if name in ("k", "v"):       # (B, cap, KV, hd)
-            spec = stack + ("batch", None, "model", None)
-        elif name in ("k_s", "v_s"):  # (B, cap, KV) int8-cache scales
-            spec = stack + ("batch", None, "model")
+        if name in ("k", "v"):       # (B, KV, cap, hd)
+            spec = stack + ("batch", "model", None, None)
+        elif name in ("k_s", "v_s"):  # (B, KV, cap) int8/4-bit scales
+            spec = stack + ("batch", "model", None)
         elif name == "pos":          # (B, cap)
             spec = stack + ("batch", None)
         elif name == "h":            # ssm (B, di, st) | rglru (B, w)

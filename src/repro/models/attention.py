@@ -135,8 +135,15 @@ def attn_apply(params, x, cfg, positions, *, causal=True, window=None,
 # shrinks the dominant HBM term of decode)
 # ---------------------------------------------------------------------------
 def init_kv_cache(cfg, batch: int, capacity: int, window=None) -> dict:
+    """The dense KV ring, head-major: ``k`` and ``v`` (B, KV, cap, hd),
+    so each KV group's keys are contiguous rows of hd, the layout the
+    grouped einsums of ``attn_decode`` read; int8 and 4-bit caches add
+    scales ``k_s`` and ``v_s`` (B, KV, cap); ``pos`` (B, cap) int32
+    (-1 = empty slot).  cap = min(capacity, window).  The serve
+    engine's decode and merge programs donate the cache and update it
+    in place."""
     cap = capacity if window is None else min(capacity, window)
-    shape = (batch, cap, cfg.n_kv_heads, cfg.hd)
+    shape = (batch, cfg.n_kv_heads, cap, cfg.hd)
     if cfg.kv_quant_bits == 4:
         # two nibbles per byte along hd: 4x smaller than bf16
         assert cfg.hd % 2 == 0
@@ -184,6 +191,7 @@ def _nib_signed(u):
 
 
 def _kv_read(cache, name):
+    """Cache entry ``name`` in f32, its scale applied: (B, KV, cap, hd)."""
     x = cache[name]
     if x.dtype == jnp.uint8:                            # 4-bit packed
         lo = _nib_signed(x & 0xF)
@@ -198,14 +206,34 @@ def _kv_read(cache, name):
     return x.astype(jnp.float32)
 
 
-def attn_decode(params, x, cache, cfg, pos, *, window=None):
-    """One-token decode.  x: (B, 1, d); pos: (B,) int32 current position.
+def _ring_hit(pos, cap):
+    """(B, cap) bool, true at each lane's ring slot ``pos % cap``."""
+    return jnp.arange(cap)[None, :] == (pos % cap)[:, None]
 
-    Attention is computed per KV group: the H query heads are viewed as
-    (KV, G) with G = H // KV, head h reading group h // G as
-    ``_repeat_kv`` would map it, so K and V are never repeated to the
-    query heads.  The cache update and the attention (not the q/k/v/o
-    projections) carry the name scope ``attention``."""
+
+def _ring_put(ring, new, hit, axis):
+    """``ring`` with ``new`` at each lane's slot: ``hit`` from
+    ``_ring_hit``, ``axis`` the ring's cap axis, ``new`` the ring's
+    shape without it.  A select over the whole ring, which XLA fuses
+    into the in-place update of a donated cache; a per-lane scatter
+    relayouts the ring on both sides of it instead."""
+    shape = [1] * ring.ndim
+    shape[0], shape[axis] = hit.shape
+    return jnp.where(hit.reshape(shape), jnp.expand_dims(new, axis), ring)
+
+
+def attn_decode(params, x, cache, cfg, pos, *, window=None):
+    """One-token decode.  x: (B, 1, d); pos: (B,) int32 current position;
+    ``cache`` the head-major ring of ``init_kv_cache``.
+
+    The new position goes to slot ``pos % cap`` of each lane by
+    ``_ring_put``, in place where the caller donates the cache, as the
+    serve engine's ``jit_decode_step`` does.  Attention is computed per
+    KV group: the H query heads are viewed as (KV, G) with G = H // KV,
+    head h reading group h // G as ``_repeat_kv`` would map it, so K and
+    V are never repeated to the query heads.  The cache update and the
+    attention (not the q/k/v/o projections) carry the name scope
+    ``attention``."""
     b, s, d = x.shape
     assert s == 1
     positions = pos[:, None]
@@ -213,51 +241,42 @@ def attn_decode(params, x, cache, cfg, pos, *, window=None):
 
     # the cache write and the attention itself, not the projections
     with jax.named_scope("attention"):
-        cap = cache["k"].shape[1]
-        slot = pos % cap                               # ring buffer
-        bidx = jnp.arange(b)
+        hit = _ring_hit(pos, cache["pos"].shape[1])
         if cfg.kv_quant_bits:
             kq, ks_ = _kv_quantize(k[:, 0], cfg.kv_quant_bits)
             vq, vs_ = _kv_quantize(v[:, 0], cfg.kv_quant_bits)
-            new_cache = {
-                "k": cache["k"].at[bidx, slot].set(kq),
-                "v": cache["v"].at[bidx, slot].set(vq),
-                "k_s": cache["k_s"].at[bidx, slot].set(ks_),
-                "v_s": cache["v_s"].at[bidx, slot].set(vs_),
-                "pos": cache["pos"].at[bidx, slot].set(pos),
-            }
+            new = {"k": kq, "v": vq, "k_s": ks_, "v_s": vs_}
         else:
-            new_cache = {
-                "k": cache["k"].at[bidx, slot].set(
-                    k[:, 0].astype(jnp.bfloat16)),
-                "v": cache["v"].at[bidx, slot].set(
-                    v[:, 0].astype(jnp.bfloat16)),
-                "pos": cache["pos"].at[bidx, slot].set(pos),
-            }
+            new = {"k": k[:, 0].astype(jnp.bfloat16),
+                   "v": v[:, 0].astype(jnp.bfloat16)}
+        new_cache = {n: _ring_put(cache[n], t, hit, 2)
+                     for n, t in new.items()}
+        new_cache["pos"] = _ring_put(cache["pos"], pos, hit, 1)
         ck = _kv_read(new_cache, "k")
         cv = _kv_read(new_cache, "v")
         cp = new_cache["pos"]
 
-        n_kv, hd = ck.shape[2], ck.shape[3]
+        n_kv, hd = ck.shape[1], ck.shape[3]
         scale = cfg.hd ** -0.5
         qg = (q.astype(jnp.float32) * scale).reshape(b, n_kv, -1, hd)
         qg = shard(qg, "batch", "model", None, None)
-        ck = shard(ck, "batch", None, "model", None)
-        cv = shard(cv, "batch", None, "model", None)
-        s_ = jnp.einsum("bkgd,bckd->bkgc", qg, ck, precision=F32)
+        ck = shard(ck, "batch", "model", None, None)
+        cv = shard(cv, "batch", "model", None, None)
+        s_ = jnp.einsum("bkgd,bkcd->bkgc", qg, ck, precision=F32)
         valid = (cp >= 0) & (cp <= pos[:, None])
         if window is not None:
             valid = valid & (cp > pos[:, None] - window)
         s_ = jnp.where(valid[:, None, None, :], s_, NEG_INF)
         p = jax.nn.softmax(s_, axis=-1)
-        out = jnp.einsum("bkgc,bckd->bkgd", p, cv, precision=F32)
+        out = jnp.einsum("bkgc,bkcd->bkgd", p, cv, precision=F32)
         out = out.reshape(b, 1, -1, hd).astype(x.dtype)
     y = jnp.einsum("bshk,hkd->bsd", out, dq(params["wo"]))
     return y, new_cache
 
 
 def prefill_kv_cache(params, x, cfg, positions, capacity, window=None):
-    """Build a cache from a prefilled sequence (keys of the last `cap`)."""
+    """Build a head-major cache from a prefilled sequence (keys of the
+    last `cap`), each position at ring slot pos % cap."""
     b, s, d = x.shape
     _, k, v = _qkv(params, x, x, cfg, positions, positions)
     cap = capacity if window is None else min(capacity, window)
@@ -272,21 +291,22 @@ def prefill_kv_cache(params, x, cfg, positions, capacity, window=None):
     slot = jnp.where(ps >= 0, ps % cap, jnp.arange(cap)[None, :] % cap)
     bidx = jnp.arange(b)[:, None]
     cache = init_kv_cache(cfg, b, cap)
+
+    def place(name, x):
+        """x (B, cap, KV, ...) in sequence order into the ring: the
+        indices, split by the KV slice, index x's leading (B, cap)."""
+        return cache[name].at[bidx, :, slot].set(x)
+
     if cfg.kv_quant_bits:
         kq, ks_ = _kv_quantize(ks, cfg.kv_quant_bits)
         vq, vs_ = _kv_quantize(vs, cfg.kv_quant_bits)
-        return {
-            "k": cache["k"].at[bidx, slot].set(kq),
-            "v": cache["v"].at[bidx, slot].set(vq),
-            "k_s": cache["k_s"].at[bidx, slot].set(ks_),
-            "v_s": cache["v_s"].at[bidx, slot].set(vs_),
-            "pos": cache["pos"].at[bidx, slot].set(ps),
-        }
-    return {
-        "k": cache["k"].at[bidx, slot].set(ks.astype(jnp.bfloat16)),
-        "v": cache["v"].at[bidx, slot].set(vs.astype(jnp.bfloat16)),
-        "pos": cache["pos"].at[bidx, slot].set(ps),
-    }
+        new = {"k": place("k", kq), "v": place("v", vq),
+               "k_s": place("k_s", ks_), "v_s": place("v_s", vs_)}
+    else:
+        new = {"k": place("k", ks.astype(jnp.bfloat16)),
+               "v": place("v", vs.astype(jnp.bfloat16))}
+    new["pos"] = cache["pos"].at[bidx, slot].set(ps)
+    return new
 
 
 # ---------------------------------------------------------------------------
@@ -452,21 +472,18 @@ def mla_decode(params, x, cache, cfg, pos):
     """One-token MLA against the latent cache, in the absorbed form.
     x: (B, 1, d); pos: (B,) int32.  The cache write and the attention
     carry the name scope ``attention`` inside ``mla``."""
-    b = x.shape[0]
     positions = pos[:, None]
     q_nope, q_pe = _mla_q(params, x, cfg, positions)
     c, k_pe = _mla_latent(params, x, cfg, positions)
     w_uk = dq(params["wk_b"]).astype(jnp.float32)     # (r, H, nope)
     w_uv = dq(params["wv_b"]).astype(jnp.float32)     # (r, H, v)
     with jax.named_scope("attention"):
-        cap = cache["c"].shape[1]
-        slot = pos % cap
-        bidx = jnp.arange(b)
+        hit = _ring_hit(pos, cache["pos"].shape[1])
         new_cache = {
-            "c": cache["c"].at[bidx, slot].set(c[:, 0].astype(jnp.bfloat16)),
-            "k_pe": cache["k_pe"].at[bidx, slot].set(
-                k_pe[:, 0].astype(jnp.bfloat16)),
-            "pos": cache["pos"].at[bidx, slot].set(pos),
+            "c": _ring_put(cache["c"], c[:, 0].astype(jnp.bfloat16), hit, 1),
+            "k_pe": _ring_put(cache["k_pe"],
+                              k_pe[:, 0].astype(jnp.bfloat16), hit, 1),
+            "pos": _ring_put(cache["pos"], pos, hit, 1),
         }
         cc = new_cache["c"].astype(jnp.float32)           # (B, cap, r)
         cr = new_cache["k_pe"].astype(jnp.float32)        # (B, cap, rope)

@@ -119,7 +119,7 @@ def _block_apply(params, h, cfg, btype, positions, mode, cache,
                 y = attn.mla_apply(params["attn"], x, cfg, positions,
                                    causal=causal)
                 if mode == "prefill":
-                    cap = cache["kv"]["c"].shape[1]
+                    cap = cache["kv"]["pos"].shape[1]
                     new_cache["kv"] = attn.prefill_mla_cache(
                         params["attn"], x, cfg, positions, cap)
         elif mode == "decode":
@@ -130,7 +130,7 @@ def _block_apply(params, h, cfg, btype, positions, mode, cache,
             y = attn.attn_apply(params["attn"], x, cfg, positions,
                                 causal=causal, window=window)
             if mode == "prefill":
-                cap = cache["kv"]["k"].shape[1]
+                cap = cache["kv"]["pos"].shape[1]
                 new_cache["kv"] = attn.prefill_kv_cache(
                     params["attn"], x, cfg, positions, cap, window=window)
         h = h + y
@@ -239,12 +239,15 @@ class LM:
     # -- scanned group execution ---------------------------------------------
     def _run_unit(self, stacked, h, positions, mode, caches, unit=None,
                   enc_out=None, enc_pos=None, causal=True):
+        """Scan the stacked unit.  In decode the stacked caches ride in
+        the loop carry: each layer reads its slice and writes back what
+        it changed, in place in a donated buffer, where passing the
+        stack as ``xs`` would re-emit all of it as ``ys`` on every step.
+        Prefill builds fresh caches and emits them as ``ys``."""
         cfg = self.cfg
         unit = unit or self.unit
 
-        def body(carry, xs):
-            hh = carry
-            lp, lc = xs
+        def layer(hh, lp, lc):
             new_lc = {}
             aux = 0.0
             counts = {}
@@ -256,6 +259,29 @@ class LM:
                 new_lc[f"b{i}"] = nc
                 aux = aux + a
                 counts = _add_counts(counts, n)
+            return hh, new_lc, aux, counts
+
+        if mode == "decode":
+            def body(carry, xs):
+                hh, cs = carry
+                lp, j = xs
+                lc = jax.tree.map(
+                    lambda c: jax.lax.dynamic_index_in_dim(c, j, 0, False),
+                    cs)
+                hh, new_lc, aux, counts = layer(hh, lp, lc)
+                cs = jax.tree.map(
+                    lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                        c, n, j, 0), cs, new_lc)
+                return (hh, cs), (aux, counts)
+
+            n = jax.tree.leaves(stacked)[0].shape[0]
+            (h, new_caches), (auxs, counts) = jax.lax.scan(
+                body, (h, caches), (stacked, jnp.arange(n)))
+            return h, new_caches, jnp.sum(auxs), jax.tree.map(jnp.sum, counts)
+
+        def body(carry, xs):
+            lp, lc = xs
+            hh, new_lc, aux, counts = layer(carry, lp, lc)
             return hh, (new_lc, aux, counts)
 
         if mode == "train" and cfg.remat_policy != "none":

@@ -66,6 +66,7 @@ sampled tokens and are summed into ``stats``."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import List, Optional
 
@@ -131,17 +132,32 @@ class Request:
         return (self.t_done - self.t_admit) * 1e3
 
 
+def jit_decode(model):
+    """The engine's decode program, ``jit_decode_step``: ``model``'s
+    decode step (with its device counters where ``model.counters``
+    names some) jitted with the cache argument donated, so each step
+    updates the cache in place."""
+    if getattr(model, "counters", ()):
+        def decode_step(params, caches, tokens, pos):
+            return model.decode_step(params, caches, tokens, pos,
+                                     counts=True)
+    else:
+        decode_step = model.decode_step
+    return jax.jit(decode_step, donate_argnums=1)
+
+
 def _bucket(n: int) -> int:
     """Smallest power of two >= n (n >= 1)."""
     return 1 << max(0, int(n - 1).bit_length())
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def merge_slot(caches, one, i):
     """Write a batch-1 prefill cache ``one`` into slot ``i`` of the
-    engine's ``caches``.  The batch dim is dim 1 for scanned-stack
-    ("unit") caches, dim 0 for unstacked ("rest") layer caches.  ``i``
-    is traced, so one program serves every slot."""
+    engine's ``caches``, in place: ``caches`` is donated.  The batch dim
+    is dim 1 for scanned-stack ("unit") caches, dim 0 for unstacked
+    ("rest") layer caches.  ``i`` is traced, so one program serves every
+    slot."""
 
     def merge(path, full, src):
         keys = [getattr(q, "key", str(q)) for q in path
@@ -191,14 +207,7 @@ class ServeEngine:
         self.tokens = np.zeros((batch_slots, 1), np.int32)
         self.caches = model.init_cache(batch_slots, capacity)
         self._counters = tuple(getattr(model, "counters", ()))
-        if self._counters:
-            def decode_step(params, caches, tokens, pos):
-                return model.decode_step(params, caches, tokens, pos,
-                                         counts=True)
-
-            self._decode = jax.jit(decode_step)
-        else:
-            self._decode = jax.jit(model.decode_step)
+        self._decode = jit_decode(model)
 
         def prefill(p, t):
             return model.prefill(p, tokens=t, capacity=capacity)

@@ -465,3 +465,49 @@ def test_chunked_prefill_matches_whole_on_real_model():
         outs.append(done[0].out)
     assert outs[0] == outs[1], \
         "chunk-streamed prefill diverged from whole prefill"
+
+
+# ---------------------------------------------------------------------------
+# The cache is updated in place
+# ---------------------------------------------------------------------------
+def test_engine_updates_the_cache_in_place(monkeypatch):
+    """The decode and merge programs donate the cache: after each
+    ``step()`` the buffers it was given are deleted, JAX never warns that
+    a donated buffer was not usable, and the served tokens are those of
+    an engine with the same seed whose programs copy the cache."""
+    import warnings
+
+    from repro import configs
+    from repro.models.model import LM
+    from repro.serve import engine as engine_mod
+
+    cfg = configs.get_config("qwen2-0.5b", smoke=True)
+    model = LM(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab, n).astype(np.int32)
+               for n in (5, 9, 3, 12, 7)]
+
+    def serve(donate):
+        eng = ServeEngine(model, params, batch_slots=3, capacity=32, seed=7)
+        if not donate:
+            eng._decode = jax.jit(model.decode_step)
+        for rid, p in enumerate(prompts):
+            eng.add(Request(rid=rid, prompt=p, max_new=3 + rid % 4))
+        done = []
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            while eng.queue or any(s is not None for s in eng.slots):
+                given = jax.tree.leaves(eng.caches)
+                done += eng.step()
+                assert all(a.is_deleted() for a in given) == donate
+        assert not [w for w in caught if "donated" in str(w.message)]
+        return {r.rid: r.out for r in done}
+
+    served = serve(True)
+    with monkeypatch.context() as m:
+        m.setattr(engine_mod, "merge_slot",
+                  jax.jit(engine_mod.merge_slot.__wrapped__))
+        copied = serve(False)
+    assert len(served) == len(prompts)
+    assert served == copied

@@ -22,7 +22,7 @@ from repro import configs
 from repro.kernels import bitplane_ops, bitserial_matmul
 from repro.models import qweight
 from repro.models.model import LM
-from repro.serve.engine import merge_slot
+from repro.serve.engine import jit_decode, merge_slot
 
 V5E_HBM_BYTES = 16 * 2**30
 QWEN2_KN = [(896, 4864), (4864, 896), (896, 128)]
@@ -145,6 +145,54 @@ def test_decode_step_never_repeats_the_ring(one_chip, name, slots):
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer
 
 
+def _aliased_params(compiled) -> set:
+    """Entry parameters whose buffers the program's outputs reuse."""
+    head = compiled.as_text().split("\n", 1)[0]
+    return {int(p) for p in re.findall(
+        r"\{[0-9, ]*\}: \(([0-9]+), \{[0-9, ]*\}, (?:may|must)-alias\)", head)}
+
+
+def _largest_copy(compiled) -> int:
+    """Elements in the largest result of a ``copy`` in the program."""
+    sizes = [0]
+    for dims in re.findall(r"= \w+\[([0-9,]*)\]\S* copy(?:-done)?\(",
+                           compiled.as_text()):
+        n = 1
+        for d in dims.split(","):
+            n *= int(d) if d else 1
+        sizes.append(n)
+    return max(sizes)
+
+
+@pytest.mark.parametrize("name,slots", [("qwen2-0.5b", 64),
+                                        ("h2o-danube-1.8b", 32)])
+def test_decode_step_updates_the_ring_in_place(one_chip, name, slots):
+    """The serve engine's decode program at the backlog shape (slots x
+    1024 positions): every cache leaf is donated and its output reuses
+    its buffer, no copy holds a layer's ring (B * KV * cap * hd
+    elements) or more, as the relayouts around a per-lane scatter and
+    the whole-stack rewrite of a scanned cache did, and the scratch
+    memory stays under one layer's K ring."""
+    cfg = configs.get_config(name)
+    model = LM(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    caches = jax.eval_shape(lambda: model.init_cache(slots, 1024))
+    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    compiled = jit_decode(model).lower(
+        _on(one_chip, params), _on(one_chip, caches), tokens, pos).compile()
+
+    n_params = len(jax.tree.leaves(params))
+    n_caches = len(jax.tree.leaves(caches))
+    assert _aliased_params(compiled) == set(range(n_params,
+                                                  n_params + n_caches))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _nbytes(caches)
+    ring = slots * cfg.n_kv_heads * 1024 * cfg.hd
+    assert _largest_copy(compiled) < ring
+    assert mem.temp_size_in_bytes < 2 * ring       # one bf16 K ring
+
+
 def test_qwen2_prefill_compiles(one_chip, qwen2):
     model, params = qwen2
     tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=one_chip)
@@ -160,6 +208,22 @@ def test_merge_slot_compiles_at_chat_shape(one_chip, qwen2):
     one = _on(one_chip, jax.eval_shape(lambda: model.init_cache(1, 3072)))
     i = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     _fits(merge_slot.lower(caches, one, i).compile())
+
+
+def test_merge_slot_updates_the_cache_in_place(one_chip, qwen2):
+    """The cache merge at the chat shape donates the engine's cache:
+    every leaf's output reuses its buffer and no copy holds a layer's
+    ring, so an admission writes one slot and not the whole cache."""
+    model, _ = qwen2
+    cfg = model.cfg
+    caches = jax.eval_shape(lambda: model.init_cache(64, 3072))
+    one = _on(one_chip, jax.eval_shape(lambda: model.init_cache(1, 3072)))
+    i = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = merge_slot.lower(_on(one_chip, caches), one, i).compile()
+    assert _aliased_params(compiled) == set(range(len(jax.tree.leaves(
+        caches))))
+    assert compiled.memory_analysis().alias_size_in_bytes == _nbytes(caches)
+    assert _largest_copy(compiled) < 64 * cfg.n_kv_heads * 3072 * cfg.hd
 
 
 def test_w4_decode_step_ops_carry_the_scope_names(one_chip, qwen2):
